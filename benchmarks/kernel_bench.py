@@ -4,7 +4,7 @@ CPU timings (interpret-mode Pallas is a correctness vehicle, not perf) —
 the derived columns report work sizes and an *analytic* HBM-bytes-per-GEMM
 model so TPU projections can be made from the roofline constants.  The
 fused-vs-two-launch comparison, the per-stream HBM breakdown, and the
-paged-kernel smoke (MXU one-hot page dequant **bit-identical** to the
+paged-kernel smoke (lane-gather page dequant **bit-identical** to the
 reference flat-gather + live-page-grid attention vs oracle, with the
 analytic NULL-page HBM credit) are written to ``BENCH_kernels.json``.
 """
@@ -59,14 +59,14 @@ def hbm_bytes_per_linear(
 
 
 def paged_kernel_smoke(cfg: BCQConfig, cb) -> dict:
-    """Live-page-grid paged kernels: MXU one-hot dequant bit-identity vs
+    """Live-page-grid paged kernels: lane-gather dequant bit-identity vs
     the reference flat-gather (on the pool's own packed codes), decode +
     chunked-prefill attention vs their oracles in interpret mode, and the
     analytic HBM bytes the live-page schedule skips for NULL table slots.
     """
     from repro.kernels import ref as kref
     from repro.kernels.chunked_prefill import chunked_prefill
-    from repro.kernels.common import onehot_decode
+    from repro.kernels.common import codebook_lookup, flat_codebook
     from repro.kernels.paged_attention import paged_attention
     from repro.models import layers as mlayers
 
@@ -76,18 +76,18 @@ def paged_kernel_smoke(cfg: BCQConfig, cb) -> dict:
     vv = jax.random.normal(jax.random.PRNGKey(1), (p_pages, ps, hkv, d))
     pool = mlayers.cache_write(pool, kk, vv, 0, "bcq4", cfg, cb)
 
-    # 1) the one-hot·codebook MXU matmul is an exact table lookup: decode
+    # 1) the lane-gather codeword lookup is an exact table lookup: decode
     # the pool's own packed K codes both ways, compare BITWISE
     ccfg = dataclasses.replace(cfg, array_len=min(cfg.array_len, d))
     idx = bcq.unpack_nibbles(pool["k_idx"]).astype(jnp.int32)
     sel = bcq.unpack_nibbles(pool["k_sel"]).astype(jnp.int32)[..., : d // ccfg.block_len]
     code = (jnp.repeat(sel, ccfg.block_len, -1) * ccfg.n_entries + idx).reshape(-1, d)
-    mxu = onehot_decode(code, cb.astype(jnp.float32).reshape(-1, 1))
+    got = codebook_lookup(code, flat_codebook(cb))
     ref_gather = cb.astype(jnp.float32).reshape(-1)[code]
-    bit_identical = bool(jnp.all(mxu == ref_gather))
+    bit_identical = bool(jnp.all(got == ref_gather))
     emit(
-        "kernel_paged_mxu_dequant", 0.0,
-        f"onehot·codebook lookup bit_identical_vs_ref_gather={bit_identical} "
+        "kernel_paged_gather_dequant", 0.0,
+        f"lane-gather codebook lookup bit_identical_vs_ref_gather={bit_identical} "
         f"({code.shape[0]}x{d} page codes)",
     )
 
@@ -130,7 +130,7 @@ def paged_kernel_smoke(cfg: BCQConfig, cb) -> dict:
         f"null_skip={(masked_pages - live_pages) * page_b}B per decode tick",
     )
     return {
-        "mxu_dequant_bit_identical": bit_identical,
+        "gather_dequant_bit_identical": bit_identical,
         "decode_matches_ref": decode_ok,
         "chunked_matches_ref": chunk_ok,
         "timings_us": {"decode_interp": us_d, "chunked_interp": us_c},
@@ -215,7 +215,7 @@ def run(fast=False):
         json.dump(report, f, indent=1, default=float)
     emit("kernel_bench_json", 0.0, "wrote BENCH_kernels.json")
     if not (
-        paged["mxu_dequant_bit_identical"]
+        paged["gather_dequant_bit_identical"]
         and paged["decode_matches_ref"]
         and paged["chunked_matches_ref"]
     ):

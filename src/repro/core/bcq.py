@@ -183,20 +183,26 @@ def _select_and_index(blocks: jax.Array, codebooks: jax.Array):
 
     blocks: (..., L_b) normalized values; codebooks: (N_c, 2^B) sorted.
     Returns (sel int32 (...,), idx int32 (..., L_b)).
+
+    A running minimum over the codebooks (the first one wins ties, as
+    ``argmin`` would) keeps one codebook's candidates live at a time
+    instead of all N_c of them.
     """
 
     def one_cb(levels):
-        idx = nearest_level_idx(blocks, levels)
-        q = levels[idx]
-        err = jnp.sum((blocks - q) ** 2, axis=-1)
+        idx = nearest_level_idx(blocks, levels).astype(jnp.int32)
+        err = jnp.sum((blocks - levels[idx]) ** 2, axis=-1)
         return err, idx
 
-    errs, idxs = jax.vmap(one_cb)(codebooks)  # (N_c, ...), (N_c, ..., L_b)
-    sel = jnp.argmin(errs, axis=0)
-    idx = jnp.take_along_axis(
-        idxs, sel[None, ..., None].astype(jnp.int32), axis=0
-    )[0]
-    return sel.astype(jnp.int32), idx.astype(jnp.int32)
+    best, idx = one_cb(codebooks[0])
+    sel = jnp.zeros(best.shape, jnp.int32)
+    for c in range(1, codebooks.shape[0]):
+        err, cand = one_cb(codebooks[c])
+        better = err < best
+        best = jnp.where(better, err, best)
+        sel = jnp.where(better, c, sel)
+        idx = jnp.where(better[..., None], cand, idx)
+    return sel, idx
 
 
 @partial(jax.jit, static_argnames=("cfg",))

@@ -183,6 +183,13 @@ def e4m3_to_bits(x: jax.Array, bits: int = 8) -> jax.Array:
     return (code_e * 8 + man).astype(jnp.uint8)
 
 
+def pow2(e: jax.Array) -> jax.Array:
+    """Exact f32 ``2**e`` for integer ``e`` in the normal range, built from
+    the exponent bits — no transcendental, so a Pallas kernel on the chip
+    and XLA agree bit for bit."""
+    return jax.lax.bitcast_convert_type((e.astype(jnp.int32) + 127) << 23, jnp.float32)
+
+
 def bits_to_e4m3_impl(code: jax.Array) -> jax.Array:
     """Inverse of :func:`e4m3_to_bits` (positive scales only).  Un-jitted so
     it can be inlined inside Pallas kernel bodies."""
@@ -190,7 +197,7 @@ def bits_to_e4m3_impl(code: jax.Array) -> jax.Array:
     code_e = code // 8
     man = (code % 8).astype(jnp.float32)
     sub = 2.0**-6 * (man * 0.125)
-    nrm = 2.0 ** (code_e.astype(jnp.float32) - 7) * (1.0 + man * 0.125)
+    nrm = pow2(code_e - 7) * (1.0 + man * 0.125)
     return jnp.where(code_e == 0, sub, nrm)
 
 

@@ -89,12 +89,15 @@ def pack_params(
     ``kernel_packed`` dict of 4-bit buffers that packed-mode models expect
     (models/layers.init_qdense layout); MoE expert stacks (E, d_in, d_out)
     pack per expert (per-expert s_X), leaves gaining a leading E axis.
-    Non-GEMM leaves pass through unchanged."""
+    Non-GEMM leaves pass through unchanged.  Stacked leaves (layers or
+    experts) pack one slice at a time (``lax.map``): the encode's
+    per-codebook candidates then exist for a single (d_in, d_out) weight,
+    so peak memory does not grow with the stack depth."""
     from repro.models import layers as _layers
 
     def pack_leaf(leaf):
-        if leaf.ndim == 3:  # MoE expert stack
-            return jax.vmap(lambda w: _layers.pack_weight(w, cfg, codebooks))(leaf)
+        if leaf.ndim == 3:  # layer or MoE expert stack
+            return jax.lax.map(lambda w: _layers.pack_weight(w, cfg, codebooks), leaf)
         return _layers.pack_weight(leaf, cfg, codebooks)
 
     def walk(tree, path=""):

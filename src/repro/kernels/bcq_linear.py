@@ -4,49 +4,53 @@ DESIGN
 ======
 
 ``out = Â · Ŵᵀ`` where Â = LO-BCQ(x) is encoded **inside the kernel** and Ŵ
-arrives pre-packed (4-bit indices + selector/scale metadata).  The two-launch
-path (`bcq_quantize_pallas` + `bcq_matmul_pallas`) round-trips packed
-activations through HBM and re-decodes every weight tile O(M/TM) times with
-an O(N_c·2^B) masked-sum mux; this kernel removes both costs:
+arrives pre-packed (4-bit indices + selector/scale metadata, the
+``ops.PackedOperand`` layout — no re-layout before the launch).  The
+two-launch path (`bcq_quantize_pallas` + `bcq_matmul_pallas`) round-trips
+packed activations through HBM and re-decodes every weight tile O(M/TM)
+times with an O(N_c·2^B) masked-sum mux; this kernel removes both costs:
 
-1. **In-VMEM activation encode.**  The raw activation arrives as a full-K
-   (TM, K) VMEM slab whose block index depends only on the M tile, so Pallas
-   fetches it from HBM once per M tile (for the serving-decode hot path —
-   a single M tile — exactly once per linear, regardless of N/TN).  Each
-   (TM, TK) slice is encoded with `common.encode_tile` — the *same*
-   threshold-compare routine the standalone quantize kernel runs, so the
-   fused path is bit-exact with the two-launch path by construction.
-   Packed activations never touch HBM: the only activation HBM stream is
-   the raw bf16/f32 read.
+1. **Grid (N/TN, M/TM), whole K per step.**  Every block's lane dim is
+   either 128-aligned or the whole array (the packed selector row is
+   K/(2·L_b) bytes and the scale row K/L_A floats — never 128-aligned per
+   K tile, always legal whole).  K is walked by an in-kernel
+   ``fori_loop`` of ``tile_k`` chunks with the same f32 accumulation
+   order as the two-launch path's K grid.
 
-2. **One-hot MXU decode.**  Per scalar the decode is ``cb[sel·2^B + idx]``.
-   Instead of the N_c·2^B (~128 for the paper config) VPU compare+FMA passes
-   of the masked-sum mux, we fold the selector into a combined codeword
-   ``c = sel·2^B + idx`` and compute one
-   ``(T·TK, 2^B·N_c) · (2^B·N_c, 1)`` ``dot_general``: the one-hot row has a
-   single 1.0, so the matmul is an *exact* table lookup executed on the MXU
-   (2^B·N_c = 128 for the paper config — one systolic pass).  The one-hot is
-   materialized in row chunks of ≤4 MiB (common.onehot_decode), so VMEM
-   stays bounded for any tile size.
+2. **In-VMEM activation encode.**  The wrapper hands the kernel xᵀ, so
+   the raw activation arrives as a K-major full-K (K, TM) VMEM slab whose
+   block index depends only on the M tile: Pallas fetches it from HBM
+   once per M tile (for the serving-decode hot path — a single M tile —
+   exactly once per linear, regardless of N/TN), and each loop step takes
+   a (TK, TM) chunk at a sublane offset.  The chunk is encoded with
+   `common.encode_tile` — the *same* threshold-compare routine the
+   standalone quantize kernel runs, so the fused path is bit-exact with
+   the two-launch path by construction.  The encode already selects each
+   scalar's codeword, so the activation needs no decode at all.  Packed
+   activations never touch HBM: the only activation HBM stream is the raw
+   read (plus the wrapper's transpose).
 
-3. **Weight tile decoded once per (j, s).**  Grid = (N/TN, M/TM, K/TK) —
-   N-**outer**, M-inner, K-innermost.  The decoded f32 weight tile for
-   (j, s) is written to a persistent VMEM scratch slab at the first M step
-   (i == 0) and reused for every M revisit, so decode cost is O(1) per
-   weight tile instead of O(M/TM).  The f32 output block (i, j) accumulates
-   across the innermost K steps (standard revolving accumulator).
+3. **Lane-gather weight decode, once per N tile.**  The weight row tile
+   (TN, K) decodes at the first M step (i == 0) into a persistent VMEM
+   scratch slab reused for every M revisit, so decode cost is O(1) per
+   weight tile instead of O(M/TM).  Per 128-lane output chunk: the index
+   byte, the block selector byte and the array scale are spread over
+   their scalars and the combined codeword ``sel·2^B + idx`` is looked
+   up (``common.decode_rows``, shared with the bcq4 page dequant) — lane
+   gathers (one ``tpu.dynamic_gather`` per vreg), exact, with the
+   ≤128-entry flattened codebook held in one vreg row.
 
-VMEM budget per core (defaults TM=TN=128, TK=512, paper cfg, K = d_model):
+VMEM budget per core (defaults TM=TN=128, paper cfg, K = d_model):
 
-  raw activation slab      TM·K·4         = K·512 B   (2 MiB @ K=4096)
-  packed weight tile       ~TN·TK·0.57    ≈  36 KiB
-  decoded-weight scratch   (K/TK)·TN·TK·4 = K·TN·4 B  (2 MiB @ K=4096)
-  one-hot decode chunk     ≤ 4 MiB (chunked, common.onehot_decode)
-  encode temporaries       ~3×TM·TK·4     ≈ 768 KiB
-  f32 out block            TM·TN·4        =  64 KiB
+  raw activation slab      2·TM·K·4      = K·1 KiB   (3 MiB @ K=3072)
+  packed weight row tile   2·TN·K·0.57   ≈ K·146 B   (440 KiB @ K=3072)
+  decoded-weight scratch   TN·K·4        = K·512 B   (1.5 MiB @ K=3072)
+  encode temporaries       ~10×TM·TK·4   ≈ 2.5 MiB   (TK = 512)
+  f32 out block            2·TM·TN·4     = 128 KiB
 
-≈ 9 MiB at K=4096 — inside the ~16 MiB VMEM envelope; both slabs scale
-linearly in K, so for very large K lower ``tile_m``/``tile_n``.
+≈ 7.5 MiB at K=3072 (d_ff of gpt3_126m) — inside the 16 MiB scoped VMEM
+limit; the slabs scale linearly in K, so for very large K lower
+``tile_m``/``tile_n``.
 
 HBM traffic per linear: the packed 4.5-bit weight stream + the raw
 activation read + the f32 output — no packed-activation round-trip.  For
@@ -55,10 +59,12 @@ never changes across the whole grid, so the raw read happens exactly once;
 multi-M-tile prefill re-streams the slab per N tile like any GEMM operand.
 
 Bit-exactness vs the two-launch path: identical encode (shared
-`encode_tile`), identical decoded values (the one-hot dot reproduces
-``cb[sel·2^B+idx]`` exactly; additions of exact 0.0 products), identical
-dequant scales (same ``1/(ŝ_A·s_X)`` f32 arithmetic), and identical
-accumulation order over K — tested bitwise in tests/test_fused_linear.py.
+`encode_tile`), identical decoded values (a gather moves bits; the
+encode's chosen codeword is ``cb[sel, idx]`` exactly), identical dequant
+scales (same ``1/(ŝ_A·s_X)`` f32 arithmetic), and identical accumulation
+order over K — tested bitwise in tests/test_fused_linear.py (interpret
+mode).  On the chip the final dot runs at the MXU's default precision;
+chip_smoke.py states the tolerance against the f32 oracle.
 """
 from __future__ import annotations
 
@@ -71,49 +77,54 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bcq import BCQConfig
 from repro.kernels.common import (
+    LANES,
+    decode_rows,
     encode_tile,
-    onehot_decode,
+    expand_lanes,
+    flat_codebook,
     resolve_interpret,
-    unpack_u4,
 )
 
 
 def _fused_kernel(
-    x_ref, w_idx_ref, w_sel_ref, w_inv_ref, cb_ref, cbf_ref, sx_ref,
-    out_ref, w_cache, *, cfg: BCQConfig, tile_n: int, tile_k: int,
+    xt_ref, w_idx_ref, w_sel_ref, w_inv_ref, cb_ref, cbr_ref, sx_ref,
+    out_ref, w_cache, *, cfg: BCQConfig, tile_k: int, n_k: int,
 ):
-    i = pl.program_id(1)  # M tile (grid = (N/TN, M/TM, K/TK))
-    s = pl.program_id(2)  # K step
-    lb, la, ne = cfg.block_len, cfg.array_len, cfg.n_entries
-    cb = cb_ref[...]
-    cbf = cbf_ref[...]
+    i = pl.program_id(1)  # M tile (grid = (N/TN, M/TM))
+    la = cfg.array_len
 
-    # --- weight tile: decode once per (j, s), cached across M revisits ----
+    # --- weight row tile: decode once per N tile, cached across M revisits
     @pl.when(i == 0)
     def _decode_weight():
-        w_idx = unpack_u4(w_idx_ref[...])                 # (TN, TK)
-        w_sel = unpack_u4(w_sel_ref[...])                 # (TN, TK/Lb)
-        code = jnp.repeat(w_sel, lb, axis=-1) * ne + w_idx
-        vals = onehot_decode(code, cbf)                   # (TN, TK) f32
-        inv = jnp.repeat(w_inv_ref[...], la, axis=-1)
-        w_cache[pl.ds(s * tile_n, tile_n), :] = vals * inv
+        w = decode_rows(
+            w_idx_ref[...].astype(jnp.int32), w_sel_ref[...].astype(jnp.int32),
+            w_inv_ref[...], cbr_ref[...], cfg, n_k * tile_k,
+        )
+        for s in range(n_k):
+            w_cache[s] = w[:, s * tile_k : (s + 1) * tile_k]
 
-    # --- activation tile: encode in VMEM, decode via one-hot MXU ----------
-    # x_ref holds the full-K (TM, K) slab (fetched once per M tile); take
-    # this K step's (TM, TK) slice.
-    x = x_ref[:, pl.ds(s * tile_k, tile_k)].astype(jnp.float32)
-    s_x = sx_ref[0, 0]
-    idx, sel, ratio = encode_tile(x, cb, s_x, cfg, tile_k)
-    code = jnp.repeat(sel, lb, axis=-1) * ne + idx
-    a = onehot_decode(code, cbf) * jnp.repeat(1.0 / (ratio * s_x), la, axis=-1)
+    # --- activation: encode each K chunk in VMEM, accumulate on the MXU --
+    s_x = sx_ref[0]
 
-    @pl.when(s == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    def k_step(s, acc):
+        k0 = pl.multiple_of(s * tile_k, tile_k)
+        xt = xt_ref[pl.ds(k0, tile_k), :].astype(jnp.float32)  # K-major (TK, TM)
+        _, _, ratio, q = encode_tile(xt, cb_ref, s_x, cfg)
+        inv = (1.0 / (ratio * s_x)).T  # (TM, TK/L_A)
+        inv = jnp.concatenate(
+            [expand_lanes(inv, la, c) for c in range(-(-tile_k // LANES))], axis=1
+        )[:, :tile_k]
+        # scale AFTER the transpose: the dot's operand is then a product,
+        # which XLA cannot fold into the dot's dimension numbers — so the
+        # interpreter accumulates exactly like the two-launch path
+        a = q.T * inv  # (TM, TK)
+        return acc + jax.lax.dot_general(
+            a, w_cache[s], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    w = w_cache[pl.ds(s * tile_n, tile_n), :]
-    out_ref[...] += jax.lax.dot_general(
-        a, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    out_ref[...] = jax.lax.fori_loop(
+        0, n_k, k_step, jnp.zeros(out_ref.shape, jnp.float32)
     )
 
 
@@ -140,31 +151,33 @@ def bcq_linear_pallas(
     with padded-K arrays zeroed (they then contribute exact zeros regardless
     of the activation tile's padding codes).  s_x: per-tensor activation
     scale (global reduction, computed by the caller).  Caller pads to tile
-    multiples (ops.py).  ``interpret=None`` auto-detects the backend."""
+    multiples (ops.py); natively ``tile_k`` must be a multiple of 128.
+    ``interpret=None`` auto-detects the backend."""
     m, k = x.shape
     n = w_idx.shape[0]
     assert m % tile_m == 0 and n % tile_n == 0 and k % tile_k == 0
     assert tile_k % cfg.array_len == 0 and tile_k % (2 * cfg.block_len) == 0
-    spb = cfg.block_len * 2
     n_k = k // tile_k
-    grid = (n // tile_n, m // tile_m, n_k)
-    cb = codebooks.astype(jnp.float32)
-    cb_flat = cb.reshape(-1, 1)
-    kernel = functools.partial(_fused_kernel, cfg=cfg, tile_n=tile_n, tile_k=tile_k)
+    kernel = functools.partial(_fused_kernel, cfg=cfg, tile_k=tile_k, n_k=n_k)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n // tile_n, m // tile_m),
         in_specs=[
-            pl.BlockSpec((tile_m, k), lambda j, i, s: (i, 0)),
-            pl.BlockSpec((tile_n, tile_k // 2), lambda j, i, s: (j, s)),
-            pl.BlockSpec((tile_n, tile_k // spb), lambda j, i, s: (j, s)),
-            pl.BlockSpec((tile_n, tile_k // cfg.array_len), lambda j, i, s: (j, s)),
-            pl.BlockSpec(cb.shape, lambda j, i, s: (0, 0)),
-            pl.BlockSpec(cb_flat.shape, lambda j, i, s: (0, 0)),
-            pl.BlockSpec((1, 1), lambda j, i, s: (0, 0)),
+            pl.BlockSpec((k, tile_m), lambda j, i: (0, i)),
+            pl.BlockSpec((tile_n, w_idx.shape[1]), lambda j, i: (j, 0)),
+            pl.BlockSpec((tile_n, w_sel.shape[1]), lambda j, i: (j, 0)),
+            pl.BlockSpec((tile_n, w_inv.shape[1]), lambda j, i: (j, 0)),
+            smem,
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+            smem,
         ],
-        out_specs=pl.BlockSpec((tile_m, tile_n), lambda j, i, s: (i, j)),
+        out_specs=pl.BlockSpec((tile_m, tile_n), lambda j, i: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((n_k * tile_n, tile_k), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n_k, tile_n, tile_k), jnp.float32)],
         interpret=resolve_interpret(interpret),
-    )(x, w_idx, w_sel, w_inv, cb, cb_flat, s_x.reshape(1, 1).astype(jnp.float32))
+        name="bcq_linear",
+    )(
+        x.T, w_idx, w_sel, w_inv, codebooks.astype(jnp.float32),
+        flat_codebook(codebooks), s_x.reshape(1).astype(jnp.float32),
+    )

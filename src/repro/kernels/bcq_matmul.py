@@ -16,7 +16,7 @@ Grid (M/TM, N/TN, K/TK), K innermost for revolving accumulation into the
 HBM traffic per operand tile is the 4-bit packed stream + 0.5-bit metadata —
 the paper's compression is what the memory roofline sees.  For the
 single-launch variant that also encodes the activations in VMEM (and
-replaces the masked-sum mux with a one-hot MXU decode) see bcq_linear.py.
+replaces the masked-sum mux with a lane-gather decode) see bcq_linear.py.
 """
 from __future__ import annotations
 

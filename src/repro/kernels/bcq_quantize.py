@@ -23,17 +23,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bcq import BCQConfig
 from repro.kernels.common import encode_tile, pack_u4, resolve_interpret
 
 
-def _quantize_kernel(x_ref, cb_ref, sx_ref, idx_ref, sel_ref, ratio_ref, *, cfg: BCQConfig, tile_k: int):
-    x = x_ref[...].astype(jnp.float32)  # (TM, TK)
-    idx, sel, ratio = encode_tile(x, cb_ref[...], sx_ref[0, 0], cfg, tile_k)
-    idx_ref[...] = pack_u4(idx)
-    sel_ref[...] = pack_u4(sel)
-    ratio_ref[...] = ratio
+def _quantize_kernel(x_ref, cb_ref, sx_ref, idx_ref, sel_ref, ratio_ref, *, cfg: BCQConfig):
+    xt = x_ref[...].astype(jnp.float32).T  # K-major (TK, TM)
+    idx, sel, ratio, _ = encode_tile(xt, cb_ref, sx_ref[0], cfg)
+    idx_ref[...] = pack_u4(idx.T)
+    sel_ref[...] = pack_u4(sel.T)
+    ratio_ref[...] = ratio.T
 
 
 @functools.partial(
@@ -55,14 +56,14 @@ def bcq_quantize_pallas(
     assert m % tile_m == 0 and k % tile_k == 0 and tile_k % cfg.array_len == 0
     grid = (m // tile_m, k // tile_k)
     bpb = cfg.block_len * 2  # K scalars per packed selector byte
-    kernel = functools.partial(_quantize_kernel, cfg=cfg, tile_k=tile_k)
+    kernel = functools.partial(_quantize_kernel, cfg=cfg)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_m, tile_k), lambda i, j: (i, j)),
-            pl.BlockSpec(codebooks.shape, lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((tile_m, tile_k // 2), lambda i, j: (i, j)),
@@ -75,4 +76,4 @@ def bcq_quantize_pallas(
             jax.ShapeDtypeStruct((m, k // cfg.array_len), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
-    )(x, codebooks, s_x.reshape(1, 1).astype(jnp.float32))
+    )(x, codebooks.astype(jnp.float32), s_x.reshape(1).astype(jnp.float32))

@@ -6,7 +6,7 @@ core (``kernels.common.page_gather_attention`` — DESIGN lives there).  The
 chunk's queries attend to every page the sequence references through its
 scalar-prefetched block table — prefix-hit pages written by *other*
 requests included — with quantized pages dequantized **in-kernel** (bcq4
-via the one-hot·codebook MXU matmul) and a **live-page-only grid**:
+via lane gathers from the flattened codebook) and a **live-page-only grid**:
 sequence b contributes ``ceil((n_past+C)/ps)`` steps, so NULL table
 padding and absent sequences move zero HBM bytes.
 
@@ -41,7 +41,6 @@ def chunked_prefill(
     cfg: BCQConfig,
     cb: jax.Array | None = None,
     interpret: bool | None = None,
-    double_buffer: bool | None = None,
 ) -> jax.Array:
     """Chunked prefill attention: q (B, C, H, D) against a single-layer pool.
 
@@ -50,10 +49,8 @@ def chunked_prefill(
     block_tables (B, MAXP) int32; n_past (B,) tokens in pages BEFORE this
     chunk (query c is at absolute position n_past[b] + c; the sequence
     must reference ≥ n_past + C written tokens through its table).
-    ``double_buffer`` — two-slot hand-rolled page DMAs (default: native
-    TPU only); see ``page_gather_attention``.  Returns (B, C, H, D) f32."""
+    Returns (B, C, H, D) f32."""
     kv_len = n_past.astype("int32") + q.shape[1]
     return page_gather_attention(
-        q, pool, block_tables, kv_len, kind, cfg, cb, interpret,
-        double_buffer,
+        q, pool, block_tables, kv_len, kind, cfg, cb, interpret
     )

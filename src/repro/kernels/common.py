@@ -9,11 +9,29 @@ Single home for the pieces that used to be copy-pasted across
 * the threshold-compare LO-BCQ encode of one VMEM tile (``encode_tile``),
   used by both the standalone quantize kernel and the fused linear kernel —
   sharing the code is what makes the two paths bit-exact by construction,
-* the one-hot → codebook ``dot_general`` decode (``onehot_decode``) that
-  turns per-scalar codeword lookup into MXU work (see bcq_linear.py DESIGN),
+* the packed-row decode ``decode_rows`` (weight row tiles of the fused
+  linear and bcq4 pages alike), built on the codeword lookup
+  ``codebook_lookup`` and the metadata expansion ``expand_lanes``:
+  per-128-lane-chunk ``take_along_axis`` lane gathers (one
+  ``tpu.dynamic_gather`` per vreg on the chip) — see bcq_linear.py DESIGN,
 * the **page-gather attention core** (``page_gather_attention``) shared by
   the paged decode kernel (kernels/paged_attention.py) and the chunked
   prefill kernel (kernels/chunked_prefill.py) — DESIGN below.
+
+TPU LAYOUT RULES THESE HELPERS FOLLOW
+=====================================
+
+Mosaic tiles a vector's last two dims as (8 sublanes, 128 lanes).  It
+lowers reshapes that split or merge the *sublane* or leading dims, lane
+concatenation, 2-D transposes and batched matmuls with a leading batch
+dim; it refuses a reshape that splits the lane dim (``(T, 512) →
+(T, 8, 64)``) and the stack-and-reshape nibble interleave.  So the encode
+runs K-major (the reduction axis on sublanes: per-array / per-block
+reductions are sublane reductions), per-scalar metadata is expanded by
+lane gathers from 128-lane source chunks, and the codeword table lookup
+is a lane gather from the ≤128-entry flattened codebook held in one vreg
+row.  Every lookup is exact (a gather moves bits), so the chip and the
+interpreter decode identical values.
 
 PAGE-GATHER CORE DESIGN
 =======================
@@ -36,23 +54,24 @@ chunk query at ``qpos = kv_len - C + c`` does under the single mask
    arrays (``sid``/``pin``/``first``/``last``/``live``, one int32 per
    step) ride in scalar memory via ``PrefetchScalarGridSpec``.
 
-2. **MXU one-hot dequant for bcq4 pages.**  Per-scalar codeword lookup
-   ``cb[sel·2^B + idx]`` runs as ``onehot_decode`` — one
-   ``(ps·Hkv, d)``-row one-hot · flattened-codebook ``dot_general`` on the
-   MXU instead of a VPU flat-gather, exactly like the fused linear kernel
-   (bcq_linear.py DESIGN).  The one-hot matmul is an *exact* lookup (one
-   1.0 per row, exact 0.0 elsewhere), so the dequantized page is
-   bit-identical to the reference gather.
+2. **Lane-gather dequant for bcq4 pages.**  The page's (head, token)
+   vectors are decoded as rows by ``decode_rows``: index and selector
+   bytes spread over their scalars by lane gathers, the nibble picked by
+   lane parity (no stack-and-reshape), and ``cb[sel·2^B + idx]`` looked
+   up by a lane gather from the flattened codebook.  Gathers move bits,
+   so the dequantized page is bit-identical to the reference gather.
 
-3. **Repeat-free GQA.**  q reshapes to ``(C, Hkv, rep, D)`` and the score
-   / accumulate einsums batch over the Hkv groups — the old
-   ``jnp.repeat(kf, rep, axis=1)`` materialized the K and V pages
-   ``rep``× in VMEM for nothing.
+3. **Head-major, repeat-free GQA.**  The wrapper lays q out as
+   ``(B, Hkv, rep·C, D)``: each KV group's query rows sit on sublanes and
+   the score / accumulate contractions are batched matmuls over the
+   leading Hkv dim (the page is swapped from ``(ps, Hkv, D)`` to
+   ``(Hkv, ps, D)`` in VMEM) — K/V are never repeated ``rep``×.
 
-VMEM per step (f32): q block C·H·D·4, one K + one V page (packed bytes by
-kind), scratch m/l 2·H·C·4 + acc H·C·D·4, one-hot transient ≤
-``_ONEHOT_PASS_BYTES``.  For serving shapes (C ≤ 64, H ≤ 32, D ≤ 128,
-ps ≤ 64) that is well under 2 MiB — far inside the ~16 MiB envelope.
+VMEM per step (f32): q block Hkv·R·D, one K + one V page (packed bytes by
+kind; double-buffered: two of each), scratch m/l 2·Hkv·R + acc Hkv·R·D,
+scores Hkv·R·ps (lane-padded to 128).  For serving shapes (C ≤ 64,
+H ≤ 32, D ≤ 128, ps ≤ 64) that is a few MiB at most — inside the
+~16 MiB envelope.
 
 Shape-bucketing policy (serving layer, see serving/engine.py): chunk
 length and prefill batch bucket to powers of two, block tables grow by
@@ -67,19 +86,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.bcq import BCQConfig, unpack_nibbles
-from repro.core.formats import bits_to_e4m3_impl
+from repro.core.bcq import BCQConfig
+from repro.core.formats import bits_to_e4m3_impl, pow2
 
 NEG = -1e30
 
 _E4M3_MAX = 448.0
 _E4M3_MIN_SUB = 2.0**-9
-
-# VMEM transient budget for one one-hot decode pass (bytes of f32 one-hot);
-# onehot_decode chunks its row dimension so a single (rows·C, N_c·2^B) mask
-# never exceeds this.
-_ONEHOT_PASS_BYTES = 4 << 20
+LANES = 128
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
@@ -91,10 +107,14 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 
 def e4m3_snap(a: jax.Array) -> jax.Array:
-    """Inline E4M3 round-to-nearest for positive values (kernel-safe ops)."""
-    e = jnp.clip(jnp.floor(jnp.log2(jnp.maximum(a, 1e-38))), -6.0, 8.0)
-    ulp = jnp.exp2(e - 3.0)
-    q = jnp.round(a / ulp) * ulp
+    """Inline E4M3 round-to-nearest for positive values (kernel-safe ops).
+
+    The binade exponent comes from the f32 exponent bits and every scaling
+    is by an exact power of two, so the result is bit-identical on the
+    chip, in the interpreter and in XLA (``formats.E4M3.quantize``)."""
+    bits = jax.lax.bitcast_convert_type(a, jnp.int32)
+    e = jnp.clip(((bits >> 23) & 0xFF) - 127, -6, 8)
+    q = jnp.round(a * pow2(3 - e)) * pow2(e - 3)
     q = jnp.minimum(q, _E4M3_MAX)
     return jnp.maximum(q, _E4M3_MIN_SUB)
 
@@ -115,30 +135,35 @@ def unpack_u4(p: jax.Array) -> jax.Array:
     return jnp.stack([lo, hi], axis=-1).reshape(t, n * 2)
 
 
-def encode_tile(x: jax.Array, cb: jax.Array, s_x: jax.Array, cfg: BCQConfig, tile_k: int):
-    """LO-BCQ encode of one (TM, TK) f32 tile resident in VMEM.
+def encode_tile(xt: jax.Array, cb, s_x: jax.Array, cfg: BCQConfig):
+    """LO-BCQ encode of one K-major (TK, TM) f32 tile resident in VMEM.
 
     Per block array: |max| reduce → ŝ_A = E4M3(s_A/s_X); per codebook
     (unrolled, N_c ≤ 16): per-scalar nearest sorted entry via 2^B−1
     threshold compares, block MSE, running argmin over codebooks.  All
-    compare+select+FMA on the VPU — no gather.
+    compare+select+FMA on the VPU — no gather.  K runs along sublanes, so
+    the per-array and per-block reductions are sublane reductions.
+    ``cb[i, t]`` must yield scalars (an SMEM ref or a concrete array).
 
-    Returns (idx (TM, TK) i32, sel (TM, TK/L_b) i32, ratio (TM, TK/L_A) f32).
+    Returns, K-major: idx (TK, TM) i32, sel (TK/L_b, TM) i32,
+    ratio (TK/L_A, TM) f32 and the chosen codewords ``cb[sel, idx]``
+    (TK, TM) f32.
     """
-    tm = x.shape[0]
+    tk, tm = xt.shape
     la, lb, nc, ne = cfg.array_len, cfg.block_len, cfg.n_codebooks, cfg.n_entries
-    na = tile_k // la
+    na, nb = tk // la, tk // lb
 
-    arrays = x.reshape(tm, na, la)
-    amax = jnp.max(jnp.abs(arrays), axis=-1)
+    arrays = xt.reshape(na, la, tm)
+    amax = jnp.max(jnp.abs(arrays), axis=1)
     s_a = jnp.where(amax > 0, cfg.codeword_max / amax, s_x)
     ratio = e4m3_snap(s_a / s_x)
-    y = arrays * (ratio * s_x)[..., None]
-    blocks = y.reshape(tm, na * (la // lb), lb)
+    y = arrays * (ratio * s_x)[:, None, :]
+    blocks = y.reshape(nb, lb, tm)
 
-    best_err = jnp.full(blocks.shape[:-1], jnp.inf, jnp.float32)
-    best_sel = jnp.zeros(blocks.shape[:-1], jnp.int32)
+    best_err = jnp.full((nb, tm), jnp.inf, jnp.float32)
+    best_sel = jnp.zeros((nb, tm), jnp.int32)
     best_idx = jnp.zeros(blocks.shape, jnp.int32)
+    best_q = jnp.zeros(blocks.shape, jnp.float32)
     for i in range(nc):  # unrolled: N_c ≤ 16
         lv = [cb[i, t] for t in range(ne)]
         idx = jnp.zeros(blocks.shape, jnp.int32)
@@ -147,45 +172,95 @@ def encode_tile(x: jax.Array, cb: jax.Array, s_x: jax.Array, cfg: BCQConfig, til
         q = jnp.zeros(blocks.shape, jnp.float32)
         for t in range(ne):  # masked-sum decode (no gather on TPU)
             q += jnp.where(idx == t, lv[t], 0.0)
-        err = jnp.sum((blocks - q) ** 2, axis=-1)
+        err = jnp.sum((blocks - q) ** 2, axis=1)
         take = err < best_err
         best_err = jnp.where(take, err, best_err)
         best_sel = jnp.where(take, i, best_sel)
-        best_idx = jnp.where(take[..., None], idx, best_idx)
+        best_idx = jnp.where(take[:, None, :], idx, best_idx)
+        best_q = jnp.where(take[:, None, :], q, best_q)
 
-    return (
-        best_idx.reshape(tm, tile_k),
-        best_sel.reshape(tm, na * (la // lb)),
-        ratio,
-    )
+    return best_idx.reshape(tk, tm), best_sel, ratio, best_q.reshape(tk, tm)
 
 
-def onehot_decode(code: jax.Array, cb_flat: jax.Array) -> jax.Array:
-    """Decode combined codewords via a one-hot · codebook matmul (MXU).
+def _lane_gather(src: jax.Array, idx: jax.Array) -> jax.Array:
+    """out[r, l] = src[r, idx[r, l]] for one (R, 128) vreg-wide chunk."""
+    return jnp.take_along_axis(src, idx, axis=1, mode="promise_in_bounds")
 
-    code: (T, C) int32 combined codeword sel·2^B + idx per scalar;
-    cb_flat: (N_c·2^B, 1) f32 flattened codebook table.  Returns f32 (T, C)
-    with value cb_flat[code] — exact, because the one-hot row has a single
-    1.0 and every other product is an exact 0.0.
 
-    The (rows·C, N_c·2^B) one-hot is materialized in row chunks so a pass
-    stays under ``_ONEHOT_PASS_BYTES`` of VMEM (see bcq_linear.py DESIGN).
-    """
-    t, c = code.shape
-    n = cb_flat.shape[0]
-    rows = max(1, _ONEHOT_PASS_BYTES // (4 * c * n))
-    rows = min(rows, t)
-    while t % rows:  # static: largest divisor of T under the budget
-        rows -= 1
-    dnums = (((1,), (0,)), ((), ()))
+def _pad_lanes(v: jax.Array, mult: int = LANES) -> jax.Array:
+    pad = (-v.shape[-1]) % mult
+    if not pad:
+        return v
+    return jnp.concatenate([v, jnp.zeros(v.shape[:-1] + (pad,), v.dtype)], axis=-1)
+
+
+def expand_lanes(src: jax.Array, rep: int, chunk: int) -> jax.Array:
+    """Lane ``l`` of 128-lane output chunk ``chunk`` ← ``src[:, (chunk·128
+    + l) // rep]``: per-array scales (rep = L_A), per-block selector bytes
+    (rep = 2·L_b) or index bytes (rep = 2) spread over the scalars they
+    cover.  ``src`` (R, n) is lane-padded to 128 here; the gather reads
+    one 128-lane source chunk, so ``rep`` must divide 128."""
+    assert LANES % rep == 0, rep
+    base = chunk * LANES // rep
+    blk = base // LANES * LANES
+    s = _pad_lanes(src)[:, blk : blk + LANES]
+    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return _lane_gather(s, (base - blk) + lane // rep)
+
+
+def codebook_lookup(code: jax.Array, cb_row: jax.Array) -> jax.Array:
+    """Decode combined codewords ``cb_flat[code]`` by lane gathers.
+
+    code: (R, C) int32 combined codeword sel·2^B + idx per scalar;
+    cb_row: (1, T) f32 flattened codebook table, T a multiple of 128
+    (``flat_codebook`` pads it).  Each 128-lane chunk of ``code`` gathers
+    from the table's 128-entry vreg rows (T = 128 for the paper config:
+    one gather per vreg); wider tables select among their 128-entry
+    pieces.  Exact: a gather moves bits."""
+    r, c = code.shape
+    tabs = [
+        jnp.broadcast_to(cb_row[:, t0 : t0 + LANES], (r, LANES))
+        for t0 in range(0, cb_row.shape[1], LANES)
+    ]
+    outs = []
+    for c0 in range(0, c, LANES):
+        w = min(LANES, c - c0)
+        cc = _pad_lanes(code[:, c0 : c0 + w])
+        v = _lane_gather(tabs[0], jnp.minimum(cc, LANES - 1))
+        for k in range(1, len(tabs)):
+            hit = cc >= k * LANES
+            v = jnp.where(hit, _lane_gather(tabs[k], jnp.clip(cc - k * LANES, 0, LANES - 1)), v)
+        outs.append(v[:, :w])
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+def flat_codebook(cb: jax.Array) -> jax.Array:
+    """(N_c, 2^B) codebooks → the (1, T) f32 lookup row of
+    ``codebook_lookup`` (flattened sel·2^B + idx order, lane-padded)."""
+    return _pad_lanes(cb.astype(jnp.float32).reshape(1, -1))
+
+
+def decode_rows(idx_b, sel_b, inv, cb_row, cfg: BCQConfig, width: int) -> jax.Array:
+    """Dequantize R packed LO-BCQ rows to f32 (R, width), 128 lanes at a time.
+
+    idx_b (R, width/2) and sel_b (R, ≥width/2L_b) are the packed index /
+    selector bytes as int32 (low nibble first), inv (R, width/L_A) the f32
+    per-array dequant scales — a weight row tile of the fused linear and a
+    bcq4 page's (token, head) vectors alike.  Each byte is spread over its
+    scalars by ``expand_lanes`` and its nibble picked by lane parity (no
+    stack-and-reshape), then the combined codeword is looked up."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (idx_b.shape[0], LANES), 1)
+    lb = cfg.block_len
     chunks = []
-    for r0 in range(0, t, rows):
-        blk = code[r0 : r0 + rows].reshape(rows * c, 1)
-        col = jax.lax.broadcasted_iota(jnp.int32, (rows * c, n), 1)
-        oh = (blk == col).astype(jnp.float32)
-        v = jax.lax.dot_general(oh, cb_flat, dnums, preferred_element_type=jnp.float32)
-        chunks.append(v.reshape(rows, c))
-    return chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=0)
+    for c in range(-(-width // LANES)):
+        ib = expand_lanes(idx_b, 2, c)
+        idx = jnp.where(lane % 2 == 0, ib & 0xF, ib >> 4)
+        sb = expand_lanes(sel_b, 2 * lb, c)
+        sel = jnp.where((lane // lb) % 2 == 0, sb & 0xF, sb >> 4)
+        vals = codebook_lookup(sel * cfg.n_entries + idx, cb_row)
+        chunks.append(vals * expand_lanes(inv, cfg.array_len, c))
+    out = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=1)
+    return out[:, :width]
 
 
 # ===================================================================== #
@@ -210,27 +285,28 @@ def page_pool_leaves(pool: dict, kind: str) -> tuple[list, list]:
     raise ValueError(kind)
 
 
-def dequant_page(kind, refs, cfg: BCQConfig, cbf_ref, sx):
-    """Dequantize one page's K or V to f32 (ps, Hkv, D) inside the kernel.
+def dequant_page(kind, refs, cfg: BCQConfig, cb_ref, sx):
+    """Dequantize one page's K or V to f32 head-major (Hkv, ps, D).
 
-    bcq4 decodes via the one-hot·codebook MXU matmul (``onehot_decode``,
-    exact lookup — bit-identical to the reference flat-gather);
-    ``cbf_ref`` holds the flattened (N_c·2^B, 1) codebook."""
+    bcq4 lays the page's (head, token) vectors out as rows and decodes
+    them with ``decode_rows`` — exact, bit-identical to the reference
+    flat-gather; ``cb_ref`` holds the (1, T) lookup row."""
     if kind == "bf16":
-        return refs[0][0].astype(jnp.float32)
+        return jnp.swapaxes(refs[0][0].astype(jnp.float32), 0, 1)
     if kind == "int8":
         q = refs[0][0].astype(jnp.float32)  # (ps, Hkv, D)
         s = refs[1][0]  # (ps, Hkv) f32
-        return q * s[..., None]
-    idx = unpack_nibbles(refs[0][0]).astype(jnp.int32)  # (ps, Hkv, D)
-    ps, hkv, d = idx.shape
-    nb = d // cfg.block_len
-    sel = unpack_nibbles(refs[1][0]).astype(jnp.int32)[..., :nb]
-    ratio = bits_to_e4m3_impl(refs[2][0])  # (ps, Hkv, na)
+        return jnp.swapaxes(q * s[..., None], 0, 1)
+    ps, hkv, d2 = refs[0].shape[1:]
+
+    def rows(ref):  # (ps, Hkv, n) bytes → (Hkv·ps, n) int32, head-major
+        v = jnp.swapaxes(ref[0].astype(jnp.int32), 0, 1)
+        return v.reshape(hkv * ps, v.shape[-1])
+
+    ratio = bits_to_e4m3_impl(rows(refs[2]))
     inv = jnp.where(ratio > 0, 1.0 / (ratio * sx), 0.0)
-    code = jnp.repeat(sel, cfg.block_len, -1) * cfg.n_entries + idx
-    vals = onehot_decode(code.reshape(ps * hkv, d), cbf_ref[...])
-    return vals.reshape(ps, hkv, d) * jnp.repeat(inv, cfg.array_len, -1)
+    vals = decode_rows(rows(refs[0]), rows(refs[1]), inv, cb_ref[...], cfg, 2 * d2)
+    return vals.reshape(hkv, ps, 2 * d2)
 
 
 def page_schedule(kv_len: jax.Array, page_size: int, maxp: int):
@@ -264,7 +340,7 @@ def page_schedule(kv_len: jax.Array, page_size: int, maxp: int):
 
 def _page_gather_kernel(
     bt_ref, kvl_ref, sid_ref, pin_ref, first_ref, last_ref, live_ref,
-    *args, kind, cfg, ps, hkv, rep, scale, nq, db,
+    *args, kind, cfg, ps, rep, scale, nq,
 ):
     nk = _PAGE_NK[kind]
     q_ref = args[0]
@@ -272,60 +348,16 @@ def _page_gather_kernel(
     v_refs = args[1 + nk : 1 + 2 * nk]
     extra = args[1 + 2 * nk :]
     if kind == "bcq4":
-        sx_ref, cbf_ref = extra[0], extra[1]
-        o_ref, m_ref, l_ref, acc_ref = extra[2], extra[3], extra[4], extra[5]
-        rest = extra[6:]
-        k_sx, v_sx = sx_ref[0, 0], sx_ref[0, 1]
+        sx_ref, cb_ref = extra[0], extra[1]
+        o_ref, m_ref, l_ref, acc_ref = extra[2:]
+        k_sx, v_sx = sx_ref[0], sx_ref[1]
     else:
-        cbf_ref, k_sx, v_sx = None, None, None
-        o_ref, m_ref, l_ref, acc_ref = extra[0], extra[1], extra[2], extra[3]
-        rest = extra[4:]
+        cb_ref, k_sx, v_sx = None, None, None
+        o_ref, m_ref, l_ref, acc_ref = extra
 
     t = pl.program_id(0)
     b = sid_ref[t]
     j = pin_ref[t]
-
-    if db:
-        # Double-buffered page DMAs: the K/V pool leaves stay in ANY/HBM
-        # and each grid step hand-copies its page into one of two VMEM
-        # slots (slot = step parity) — step t issues step t+1's copies
-        # BEFORE waiting on its own, so the next page streams in while
-        # this one computes.  The schedule is scalar-prefetched, so step
-        # t+1's page id is known here; dead tail steps (live == 0) start
-        # and wait nothing, preserving the BlockSpec path's dead-step DMA
-        # elision byte-for-byte.
-        import jax.experimental.pallas.tpu as pltpu
-
-        g = pl.num_programs(0)
-        bufs = rest[: 2 * nk]
-        sems = rest[2 * nk]
-        pool_refs = list(k_refs) + list(v_refs)
-
-        def page_dmas(step):
-            s = jax.lax.rem(step, 2)
-            pid = bt_ref[sid_ref[step], pin_ref[step]]
-            return [
-                pltpu.make_async_copy(
-                    leaf.at[pid], buf.at[s], sems.at[s, li]
-                )
-                for li, (leaf, buf) in enumerate(zip(pool_refs, bufs))
-            ]
-
-        @pl.when((t == 0) & (live_ref[t] == 1))
-        def _warmup():
-            for dma in page_dmas(t):
-                dma.start()
-
-        tn = jnp.minimum(t + 1, g - 1)
-
-        @pl.when((t + 1 < g) & (live_ref[tn] == 1))
-        def _prefetch_next():
-            for dma in page_dmas(tn):
-                dma.start()
-
-        slot = jax.lax.rem(t, 2)
-        k_refs = [buf.at[pl.ds(slot, 1)] for buf in bufs[:nk]]
-        v_refs = [buf.at[pl.ds(slot, 1)] for buf in bufs[nk:]]
 
     @pl.when(first_ref[t] == 1)
     def _init():
@@ -335,40 +367,35 @@ def _page_gather_kernel(
 
     @pl.when(live_ref[t] == 1)
     def _update():
-        if db:
-            for dma in page_dmas(t):
-                dma.wait()
-        q = q_ref[0].astype(jnp.float32)  # (C, H, D)
-        d = q.shape[-1]
-        qg = q.reshape(nq, hkv, rep, d)  # GQA: batch kv groups, never repeat K/V
-        kf = dequant_page(kind, k_refs, cfg, cbf_ref, k_sx)  # (ps, Hkv, D)
-        vf = dequant_page(kind, v_refs, cfg, cbf_ref, v_sx)
+        q = q_ref[0].astype(jnp.float32)  # (Hkv, R, D), row r = rr·C + c
+        kf = dequant_page(kind, k_refs, cfg, cb_ref, k_sx)  # (Hkv, ps, D)
+        vf = dequant_page(kind, v_refs, cfg, cb_ref, v_sx)
+        hkv, nr, _ = q.shape
 
-        s = jnp.einsum("cgrd,tgd->grct", qg, kf) * scale  # (Hkv, rep, C, ps)
+        s = jnp.einsum(
+            "grd,gtd->grt", q, kf, preferred_element_type=jnp.float32
+        ) * scale  # (Hkv, R, ps)
         # query c sits at absolute position kv_len - C + c; page token u at
         # j·ps + u.  One mask gives decode validity (C == 1), chunk
         # causality, prefix visibility, and unwritten-tail hiding.
-        tpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, 1, nq, ps), 3)
-        qpos = (kvl_ref[b] - nq) + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, nq, ps), 2
-        )
-        s = jnp.where(tpos <= qpos, s, NEG)
+        tpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (hkv, nr, ps), 2)
+        c = jax.lax.broadcasted_iota(jnp.int32, (hkv, nr, ps), 1) % nq
+        s = jnp.where(tpos <= (kvl_ref[b] - nq) + c, s, NEG)
 
-        m_prev = m_ref[...].reshape(hkv, rep, nq)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=3))
-        p = jnp.exp(s - m_new[..., None])
+        m_prev = m_ref[...]  # (Hkv, R, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_ref[...].reshape(hkv, rep, nq) * alpha + jnp.sum(p, axis=3)
-        acc = acc_ref[...].reshape(hkv, rep, nq, d)
-        acc = acc * alpha[..., None] + jnp.einsum("grct,tgd->grcd", p, vf)
-        m_ref[...] = m_new.reshape(hkv * rep, nq)
-        l_ref[...] = l_new.reshape(hkv * rep, nq)
-        acc_ref[...] = acc.reshape(hkv * rep, nq, d)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
+            "grt,gtd->grd", p, vf, preferred_element_type=jnp.float32
+        )
+        m_ref[...] = m_new
 
     @pl.when(last_ref[t] == 1)
     def _done():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]  # (H, C, D)
-        o_ref[0] = out.transpose(1, 0, 2).astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def page_gather_attention(
@@ -380,7 +407,6 @@ def page_gather_attention(
     cfg: BCQConfig,
     cb: jax.Array | None = None,
     interpret: bool | None = None,
-    double_buffer: bool | None = None,
 ) -> jax.Array:
     """The shared page-gather online-softmax attention over a page pool.
 
@@ -391,17 +417,11 @@ def page_gather_attention(
     ``cache_init`` layout; block_tables (B, MAXP) int32.  Returns
     (B, C, H, D) f32.  See the module docstring for the grid schedule.
 
-    ``double_buffer``: hand-rolled two-slot page DMAs (step t prefetches
-    step t+1's K/V page while computing — see the kernel) instead of the
-    BlockSpec auto-pipeline.  None → on for native TPU, off under
-    interpret (the interpreter simulates DMAs serially, so the extra
-    machinery would only slow CPU tests); an explicit bool wins, and the
-    two paths are bit-identical (asserted in tests/test_paged_kernel.py)."""
-    import jax.experimental.pallas.tpu as pltpu
-
+    The page BlockSpecs are double-buffered (the Pallas TPU pipeline's
+    default of two buffers per input): the pipeline issues step t+1's K/V page DMAs — their block indices come
+    from the scalar-prefetched schedule — before step t computes, and
+    skips the copy when the index repeats (dead tail steps)."""
     b, nq, h, d = q.shape
-    interpret = resolve_interpret(interpret)
-    db = (not interpret) if double_buffer is None else double_buffer
     maxp = block_tables.shape[1]
     if kind == "bcq4" and d % cfg.array_len:
         # per-head-vector cache quantization shrinks L_A to d_head
@@ -411,6 +431,9 @@ def page_gather_attention(
     hkv = k_leaves[0].shape[2]
     rep = h // hkv
     assert h == hkv * rep, (h, hkv)
+    # head-major rows: KV group g holds its rep query heads × C positions
+    qg = q.reshape(b, nq, hkv, rep, d).transpose(0, 2, 3, 1, 4)
+    qg = qg.reshape(b, hkv, rep * nq, d)
 
     sid, pin, first, last, live = page_schedule(kv_len, ps, maxp)
 
@@ -430,55 +453,42 @@ def page_gather_attention(
             lambda t, bt, kvl, sid, *_, _nd=nd: (sid[t],) + (0,) * (_nd - 1),
         )
 
-    inputs = [q] + k_leaves + v_leaves
-    in_specs = [row_spec(q.shape)]
-    if db:
-        # leaves stay whole in ANY/HBM; the kernel DMAs pages by hand
-        in_specs += [
-            pl.BlockSpec(memory_space=pltpu.ANY)
-            for _ in k_leaves + v_leaves
-        ]
-    else:
-        in_specs += [page_spec(leaf) for leaf in k_leaves + v_leaves]
+    inputs = [qg] + k_leaves + v_leaves
+    in_specs = [row_spec(qg.shape)] + [page_spec(leaf) for leaf in k_leaves + v_leaves]
     if kind == "bcq4":
-        sx = jnp.stack([pool["k_sx"], pool["v_sx"]]).reshape(1, 2).astype(jnp.float32)
-        cbf = cb.astype(jnp.float32).reshape(-1, 1)
-        inputs += [sx, cbf]
+        sx = jnp.stack([pool["k_sx"], pool["v_sx"]]).astype(jnp.float32)
+        inputs += [sx, flat_codebook(cb)]
         in_specs += [
-            pl.BlockSpec((1, 2), lambda t, *_: (0, 0)),
-            pl.BlockSpec(cbf.shape, lambda t, *_: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(inputs[-1].shape, lambda t, *_: (0, 0)),
         ]
 
     kernel = functools.partial(
         _page_gather_kernel,
-        kind=kind, cfg=cfg, ps=ps, hkv=hkv, rep=rep, scale=d**-0.5, nq=nq,
-        db=db,
+        kind=kind, cfg=cfg, ps=ps, rep=rep, scale=d**-0.5, nq=nq,
     )
+    nr = rep * nq
     scratch_shapes = [
-        pltpu.VMEM((h, nq), jnp.float32),
-        pltpu.VMEM((h, nq), jnp.float32),
-        pltpu.VMEM((h, nq, d), jnp.float32),
+        pltpu.VMEM((hkv, nr, 1), jnp.float32),
+        pltpu.VMEM((hkv, nr, 1), jnp.float32),
+        pltpu.VMEM((hkv, nr, d), jnp.float32),
     ]
-    if db:
-        nk = _PAGE_NK[kind]
-        scratch_shapes += [
-            pltpu.VMEM((2,) + leaf.shape[1:], leaf.dtype)
-            for leaf in k_leaves + v_leaves
-        ]
-        scratch_shapes += [pltpu.SemaphoreType.DMA((2, 2 * nk))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(b * maxp,),
         in_specs=in_specs,
-        out_specs=row_spec(q.shape),
+        out_specs=row_spec(qg.shape),
         scratch_shapes=scratch_shapes,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nq, h, d), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, jnp.float32),
+        interpret=resolve_interpret(interpret),
+        name="page_gather_attention",
     )(
         block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
         sid, pin, first, last, live, *inputs,
     )
+    out = out.reshape(b, hkv, rep, nq, d).transpose(0, 3, 1, 2, 4)
+    return out.reshape(b, nq, h, d)

@@ -161,6 +161,16 @@ def packed_operand(pk: dict) -> PackedOperand:
     return PackedOperand(pk["idx"], pk["sel"], inv.astype(jnp.float32), kp2 * 2, n)
 
 
+def default_tile_k(k: int, cfg: BCQConfig) -> int:
+    """The fused linear's K tile: the largest of 512/384/256/128 that
+    divides K and is a whole number of arrays (no K padding at model
+    widths); 512 when none does."""
+    return next(
+        (t for t in (512, 384, 256, 128) if k % t == 0 and t % cfg.array_len == 0),
+        512,
+    )
+
+
 @partial(jax.jit, static_argnames=("cfg", "impl", "tile_m", "tile_n", "tile_k"))
 def w4a4_linear_fused(
     x: jax.Array,
@@ -171,15 +181,19 @@ def w4a4_linear_fused(
     impl: str | None = None,
     tile_m: int = 128,
     tile_n: int = 128,
-    tile_k: int = 512,
+    tile_k: int | None = None,
 ) -> jax.Array:
     """Single-launch fused W4A4 linear (kernels/bcq_linear.py): the raw
-    activation tile is encoded in VMEM and both operands decode via the
-    one-hot MXU path — packed activations never touch HBM.  Bit-exact with
+    activation tile is encoded in VMEM and the weights decode by lane
+    gathers — packed activations never touch HBM.  Bit-exact with
     :func:`w4a4_linear` at matching tile sizes.  x: (..., K); weights
     pre-encoded (N, K); ``s_x`` overrides the per-tensor activation scale
-    (defaults to the dynamic reduction over x).  Returns (..., N)."""
+    (defaults to the dynamic reduction over x).  ``tile_k`` None picks the
+    largest of 512/384/256/128 dividing K (no K padding at model widths).
+    Returns (..., N)."""
     impl = impl or _default_impl()
+    if tile_k is None:
+        tile_k = default_tile_k(w_packed.k, cfg)
     lead = x.shape[:-1]
     k = x.shape[-1]
     assert k == w_packed.k, "activation/weight reduction dims must match"

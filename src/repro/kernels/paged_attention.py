@@ -5,9 +5,10 @@ page-gather core (``kernels.common.page_gather_attention`` — DESIGN lives
 there): each sequence's pages are gathered through its block table,
 prefetched as scalars so the BlockSpec index maps can DMA exactly the
 referenced page per grid step, and quantized pages (int8 / packed-BCQ4)
-dequantize **in-kernel** in VMEM — bcq4 via the one-hot·codebook MXU
-matmul, not a VPU flat-gather.  The grid is **live-page-only**: sequence b
-contributes ``ceil(len/ps)`` steps (its live pages), never the
+dequantize **in-kernel** in VMEM — bcq4 via lane gathers from the
+flattened codebook (``common.decode_rows``).  The grid is
+**live-page-only**: sequence b contributes ``ceil(len/ps)`` steps (its
+live pages), never the
 ``(B, MAXP)`` sweep with masked NULL-page DMAs, so per decode step the
 kernel reads exactly the live packed pages of each sequence from HBM
 (≈4.7 bits/scalar for BCQ4), and NULL block-table padding moves zero
@@ -40,16 +41,13 @@ def paged_attention(
     cfg: BCQConfig,
     cb: jax.Array | None = None,
     interpret: bool | None = None,
-    double_buffer: bool | None = None,
 ) -> jax.Array:
     """Paged decode attention: q (B, H, D) against a single-layer page pool.
 
     pool leaves: (n_pages, page_size, Hkv, ...) per ``cache_init`` layout;
     block_tables (B, MAXP) int32; lengths (B,) live tokens per sequence.
-    ``double_buffer`` — two-slot hand-rolled page DMAs (default: native
-    TPU only); see ``page_gather_attention``.  Returns (B, H, D) f32."""
+    Returns (B, H, D) f32."""
     out = page_gather_attention(
-        q[:, None], pool, block_tables, lengths, kind, cfg, cb, interpret,
-        double_buffer,
+        q[:, None], pool, block_tables, lengths, kind, cfg, cb, interpret
     )
     return out[:, 0]

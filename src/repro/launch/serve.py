@@ -159,7 +159,10 @@ def serve_paged(api, params, prompts, gen_len: int, max_len: int, page_size: int
     (SSM / hybrid / enc-dec).  ``host_pages > 0`` bounds a host-RAM swap
     tier (evicted parked pages + preemption snapshots demote with
     verified integrity); ``recompress_after > 0`` arms the cold-page
-    recompression ladder (kv layout only).  Returns (tokens, engine)."""
+    recompression ladder (kv layout only).  The engines are strict: a
+    failing prefill or decode raises instead of finishing its request
+    with an error (request-level containment is what ``run_chaos``
+    tests).  Returns (tokens, engine)."""
     spec = getattr(api, "page_spec", None)
     if spec is not None and spec.layout == "state_checkpoint":
         from repro.serving.state_engine import StatePagedEngine
@@ -170,6 +173,7 @@ def serve_paged(api, params, prompts, gen_len: int, max_len: int, page_size: int
             page_size=page_size, telemetry=telemetry,
             pipeline_depth=pipeline_depth,
             host_pages=host_pages,
+            strict=True,
         )
     else:
         from repro.serving.engine import PagedEngine
@@ -182,6 +186,7 @@ def serve_paged(api, params, prompts, gen_len: int, max_len: int, page_size: int
             pipeline_depth=pipeline_depth,
             host_pages=host_pages,
             recompress_after=recompress_after,
+            strict=True,
         )
     for i in range(prompts.shape[0]):
         engine.submit(Request(rid=i, prompt=np.asarray(prompts[i]),
@@ -492,7 +497,9 @@ def main():
 
     agree = float(jnp.mean((ref == got).astype(jnp.float32)))
     toks = args.batch * args.gen
-    print(f"bf16   : {toks/t_ref:8.1f} tok/s (CPU emulation timing)")
+    dev = jax.devices()[0]
+    print(f"timings: wall clock incl. compile on {dev.platform} ({dev.device_kind})")
+    print(f"bf16   : {toks/t_ref:8.1f} tok/s")
     print(f"W4A4   : {toks/t_q:8.1f} tok/s (fake-quant path, cache={args.cache})")
     print(f"greedy token agreement W4A4 vs bf16: {agree*100:.1f}%")
 

@@ -40,19 +40,15 @@ def make_train_step(api, opt_cfg: adamw.AdamWConfig):
 def make_compressed_dp_step(api, opt_cfg: adamw.AdamWConfig, mesh, axis: str = "data"):
     """Pure-DP variant with int8 error-feedback gradient all-reduce
     (the cross-pod DCN pattern; testable on any ≥2-device mesh)."""
-    psum_fn_inner = None  # built lazily inside shard_map via lax
-
-    from jax.experimental.shard_map import shard_map
-
     data_spec = P(axis)
 
     def step(params, opt_state, err, batch):
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(P(), P(), P(), jax.tree.map(lambda _: data_spec, batch)),
             out_specs=(P(), P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         def inner(p, s, e, b):
             loss, grads = jax.value_and_grad(api.loss_fn)(p, b)

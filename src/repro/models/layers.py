@@ -72,8 +72,8 @@ class Runtime:
     paged_kernel: bool = False
     # route quant_mode='packed' linears through the fused single-launch
     # quantize→decode→GEMM (kernels/bcq_linear.py) instead of the in-graph
-    # decode_packed_weight + einsum: raw activations encode in VMEM, both
-    # operands decode via the one-hot MXU path, packed activations never
+    # decode_packed_weight + einsum: raw activations encode in VMEM, the
+    # weights decode by lane gathers, packed activations never
     # round-trip HBM.  Native Pallas on TPU; elsewhere the ref-oracle
     # composition runs (bit-exact with the two-launch kernels).
     fused_linear: bool = True
@@ -606,7 +606,6 @@ def flash_decode_sharded(q, kf, vf, valid, rt: Runtime):
 
     q: (B, 1, H, D) replicated over 'model'; kf/vf: (B, S, Hkv, D) with S
     sharded; valid: traced scalar (# valid cache slots)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = rt.mesh
@@ -639,9 +638,9 @@ def flash_decode_sharded(q, kf, vf, valid, rt: Runtime):
         )
         return acc / jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
 
-    out = shard_map(
+    out = jax.shard_map(
         core, mesh=mesh, in_specs=(qs, kvs, kvs, P()), out_specs=qs,
-        check_rep=False,
+        check_vma=False,
     )(q, kf, vf, jnp.asarray(valid))
     return out.astype(q.dtype)
 
@@ -655,7 +654,6 @@ def cache_write_sharded(cache, k_new, v_new, pos, rt: Runtime, cb):
     then let the owning shard update locally: owner = pos // shard_len,
     local offset = pos % shard_len, others pass through.  Zero collectives.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = rt.mesh
@@ -690,9 +688,9 @@ def cache_write_sharded(cache, k_new, v_new, pos, rt: Runtime, cb):
             here = jax.lax.axis_index("model") == owner
             return jnp.where(here.reshape((1,) * bm.ndim), upd, bm)
 
-        out[n] = shard_map(
+        out[n] = jax.shard_map(
             core, mesh=mesh, in_specs=(bspec, vspec, P()), out_specs=bspec,
-            check_rep=False,
+            check_vma=False,
         )(buf, val, jnp.asarray(pos))
     return out
 

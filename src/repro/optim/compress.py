@@ -19,7 +19,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 CHUNK = 2048
@@ -62,11 +61,11 @@ def make_compressed_psum(mesh, axis_name: str = "pod"):
     layout after the in-pod reduction)."""
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def f(g, err):
         return compressed_allreduce_local(g, err, axis_name)

@@ -23,6 +23,8 @@ from collections import defaultdict
 import jax
 import numpy as np
 
+from repro.launch.mesh import make_mesh
+
 
 def derive_mesh(n_devices: int | None = None, model_parallel: int = 16, multi_pod: bool = False, pod_size: int = 256):
     """Best-effort mesh for an arbitrary device count."""
@@ -31,11 +33,11 @@ def derive_mesh(n_devices: int | None = None, model_parallel: int = 16, multi_po
     if multi_pod and n > pod_size and n % pod_size == 0:
         pods = n // pod_size
         mp = min(model_parallel, pod_size)
-        return jax.make_mesh((pods, pod_size // mp, mp), ("pod", "data", "model"), devices=devs)
+        return make_mesh((pods, pod_size // mp, mp), ("pod", "data", "model"), devices=devs)
     mp = model_parallel
     while mp > 1 and n % mp:
         mp //= 2
-    return jax.make_mesh((n // mp, mp), ("data", "model"), devices=devs)
+    return make_mesh((n // mp, mp), ("data", "model"), devices=devs)
 
 
 @dataclasses.dataclass

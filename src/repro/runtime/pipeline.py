@@ -21,7 +21,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -53,11 +52,11 @@ def pipeline_apply(
     p_stage_spec = jax.tree.map(lambda _: P(axis), stage_params)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(p_stage_spec, P(axis)),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     def run(params_local, xs_local):
         # params_local: stage slice (leading dim 1); xs_local: this shard's

@@ -568,6 +568,27 @@ class PagedEngine:
             counts = {k: v - self._trace_base.get(k, 0) for k, v in counts.items()}
         return counts
 
+    def lower_steps(self) -> dict:
+        """The engine's own device steps lowered at its current shapes:
+        ``"decode"`` (one tick over every slot) and, with chunked prefill,
+        ``"chunk"`` (one full ``prefill_chunk`` tick for one slot).  Each
+        value is a ``jax.stages.Lowered``; ``.compile().as_text()`` shows
+        the program that serves requests."""
+        w = self.tables.shape[1]
+        out = {
+            "decode": self._decode.lower(
+                self.params, self.pool,
+                jnp.zeros((self.n_slots, 3 + w), jnp.int32), self._chain_tok,
+            )
+        }
+        if self.chunked:
+            c = self.prefill_chunk
+            n_cp = pages_needed(c, self.ps)
+            out["chunk"] = self._chunk_fn(c, n_cp).lower(
+                self.params, self.pool, jnp.zeros((1, c + 2 + n_cp + w), jnp.int32)
+            )
+        return out
+
     # ------------------------------------------------------------ intake
     def submit(self, req: Request):
         """Queue a request — after validating it.  An invalid request is
@@ -1451,6 +1472,8 @@ class PagedEngine:
             raise NonFiniteLogitsError(
                 f"non-finite logits at prefill completion (rid={parent.rid})"
             )
+        if parent.keep_prompt_logits:
+            parent.prompt_logits = np.asarray(logits[0, -1], np.float32)
         greedy_tok = int(nxt[0])
         row = None if parent.sampling.greedy else logits[0, -1, :]
         if parent.n_samples == 1:
@@ -1674,16 +1697,19 @@ class PagedEngine:
             return self.prefill_chunk
         return _pow2_bucket(c, self.prefill_chunk)
 
-    def _chunk_step_packed(self, params, packed, c: int, n_cp: int):
-        """One chunk-tick launch over the consolidated packed transfer.
-        The jitted splitter is cached per (chunk bucket, pages-per-chunk)
+    def _chunk_fn(self, c: int, n_cp: int):
+        """The jitted chunk step, cached per (chunk bucket, pages-per-chunk)
         in the shared per-api cache — the same retrace cadence the
         shape-bucketed multi-array step already had."""
         fn, _ = api_jit(
             self.api, ("chunk_step", int(c), int(n_cp)),
             _make_packed_chunk(self.api.prefill_from_pages_fn, int(c), int(n_cp)),
         )
-        return fn(params, self.pool, packed)
+        return fn
+
+    def _chunk_step_packed(self, params, packed, c: int, n_cp: int):
+        """One chunk-tick launch over the consolidated packed transfer."""
+        return self._chunk_fn(c, n_cp)(params, self.pool, packed)
 
     def _prefill_tick_all(self) -> int:
         """Advance EVERY prefilling slot by one chunk in a SINGLE

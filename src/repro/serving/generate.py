@@ -126,6 +126,14 @@ class Request:
     frames: Optional[np.ndarray] = dataclasses.field(
         default=None, repr=False, compare=False
     )
+    # keep_prompt_logits=True: the paged engines store the submitted
+    # prompt's last-position logits (what the first token is picked from)
+    # in ``prompt_logits`` as a host float32 (V,) array — for comparing
+    # one serving route against another on the same requests
+    keep_prompt_logits: bool = False
+    prompt_logits: Optional[np.ndarray] = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
     # --- lifecycle guard (None = unbounded) ---
     deadline_s: Optional[float] = None
     max_output_stall_ticks: Optional[int] = None

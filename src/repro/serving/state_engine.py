@@ -673,6 +673,8 @@ class StatePagedEngine(PagedEngine):
             raise NonFiniteLogitsError(
                 f"non-finite logits at prefill completion (rid={parent.rid})"
             )
+        if parent.keep_prompt_logits:
+            parent.prompt_logits = np.asarray(logits[0, -1], np.float32)
         greedy_tok = int(nxt[0])
         row = None if parent.sampling.greedy else logits[0, -1, :]
         if parent.n_samples == 1:

@@ -317,23 +317,3 @@ def test_chunked_lifts_prompt_length_limit():
     big = PagedEngine(api, params, n_slots=1, max_len=2 * MAX_LEN, page_size=PS, n_pages=16)
     ref, _ = _run(big, [Request(rid=0, prompt=long, max_new=3)])
     assert got == ref
-
-
-@pytest.mark.parametrize("kind", ("bf16", "int8", "bcq4"))
-def test_double_buffered_dma_bitwise_identical_chunked(kind):
-    """double_buffer=True (two-slot async page copies) == the BlockSpec
-    auto-pipeline, bitwise, for chunk-shaped queries over a paged prefix."""
-    pool = _pool(kind)
-    rng = np.random.default_rng(4)
-    bt = jnp.asarray(rng.integers(1, P, (3, 4)), jnp.int32)
-    n_past = jnp.asarray([0, PS, 2 * PS], jnp.int32)
-    q = jax.random.normal(jax.random.PRNGKey(5), (3, 8, 4, D))
-    auto = chunked_prefill(
-        q, pool, bt, n_past, kind, BCQ, CB, interpret=True,
-        double_buffer=False,
-    )
-    manual = chunked_prefill(
-        q, pool, bt, n_past, kind, BCQ, CB, interpret=True,
-        double_buffer=True,
-    )
-    np.testing.assert_array_equal(np.asarray(manual), np.asarray(auto))

@@ -26,6 +26,7 @@ def _run(script: str, n_dev: int = 8, timeout: int = 540):
 def test_pjit_train_step_8dev():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs.base import get_smoke
         from repro.models import zoo
@@ -34,7 +35,7 @@ def test_pjit_train_step_8dev():
         from repro.launch.train import make_train_step
         from repro.data.pipeline import DataConfig, batch_at
         assert len(jax.devices()) == 8
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_smoke("gpt3_126m")
         rt = Runtime(quant_mode="none", compute_dtype=jnp.float32, param_dtype=jnp.float32)
         api = zoo.build(cfg, rt)
@@ -69,6 +70,7 @@ def test_pjit_train_step_8dev():
 def test_compressed_dp_step_8dev():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.base import get_smoke
         from repro.models import zoo
         from repro.models.layers import Runtime
@@ -76,7 +78,7 @@ def test_compressed_dp_step_8dev():
         from repro.optim.compress import init_error_state
         from repro.launch.train import make_compressed_dp_step
         from repro.data.pipeline import DataConfig, batch_at
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         cfg = get_smoke("gpt3_126m")
         rt = Runtime(quant_mode="none", compute_dtype=jnp.float32, param_dtype=jnp.float32)
         api = zoo.build(cfg, rt)
@@ -99,11 +101,12 @@ def test_compressed_dp_step_8dev():
 def test_sharded_decode_8dev():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs.base import get_smoke
         from repro.models import zoo
         from repro.models.layers import Runtime
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_smoke("qwen1_5_32b")
         rt = Runtime(quant_mode="none", compute_dtype=jnp.float32, param_dtype=jnp.float32)
         api = zoo.build(cfg, rt)
@@ -153,10 +156,11 @@ def test_flash_decode_matches_gathered_8dev():
     out = _run("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.base import get_smoke
         from repro.models import zoo
         from repro.models.layers import Runtime
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_smoke("qwen1_5_32b")
         rt0 = Runtime(quant_mode="none", compute_dtype=jnp.float32, param_dtype=jnp.float32)
         rt1 = dataclasses.replace(rt0, flash_decode=True, mesh=mesh)

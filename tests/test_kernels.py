@@ -125,6 +125,44 @@ def test_w4a4_linear_nd_input():
     )
 
 
+@pytest.mark.parametrize("cfg", CFGS, ids=lambda c: c.tag())
+def test_decode_rows_matches_ref_decode(cfg):
+    """The in-kernel row decode (``common.decode_rows``: bytes spread over
+    their scalars by lane gathers, nibble by lane parity, codeword looked
+    up from the flattened codebook) equals ``ref.decode_ref`` bit for bit —
+    at a width that is not a whole number of 128-lane chunks, and for
+    codebook tables of 32 to 256 entries."""
+    from repro.kernels.common import decode_rows, flat_codebook
+
+    cb = _codebooks(cfg)
+    w = _dists(jax.random.PRNGKey(10), (24, 5 * cfg.array_len), jnp.float32, "heavy")
+    p = ops.quantize(w, cb, cfg, impl="ref")
+    got = decode_rows(
+        p.idx_packed.astype(jnp.int32), p.sel_packed.astype(jnp.int32),
+        p.inv_scale, flat_codebook(cb), cfg, w.shape[1],
+    )
+    want = ref.decode_ref(p.idx_packed, p.sel_packed, p.inv_scale, cb, cfg)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_e4m3_snap_matches_format_quantize():
+    """The kernel-side E4M3 snap (binade from the exponent bits, scaling by
+    exact powers of two) equals ``formats.E4M3.quantize`` bit for bit on
+    every grid point, every midpoint (ties to even) and a log-uniform
+    sweep past both ends of the range; values below the smallest
+    subnormal snap up to it, as the encode requires a non-zero scale."""
+    from repro.core import formats
+    from repro.kernels.common import e4m3_snap
+
+    grid = formats.E4M3.levels()[1:]
+    sweep = 2.0 ** np.random.default_rng(0).uniform(-14.0, 10.0, 4096)
+    a = jnp.asarray(
+        np.concatenate([grid, (grid[:-1] + grid[1:]) / 2, sweep]), jnp.float32
+    )
+    want = jnp.maximum(formats.E4M3.quantize(a), formats.E4M3.min_subnormal)
+    np.testing.assert_array_equal(np.asarray(e4m3_snap(a)), np.asarray(want))
+
+
 def test_packed_storage_bit_accounting():
     """Packed buffers realize Eq. 9's bit budget exactly (excl. codebooks).
 

@@ -422,3 +422,31 @@ def test_kv_bucketed_decode_matches_full_read():
     full = greedy_generate(api, params, prompts, 6, MAX_LEN)
     bucketed = greedy_generate(api, params, prompts, 6, MAX_LEN, kv_bucket=8)
     np.testing.assert_array_equal(np.asarray(full), np.asarray(bucketed))
+
+
+@pytest.mark.parametrize("chunked", (False, True))
+def test_prompt_logits_and_lowered_steps(chunked):
+    """``keep_prompt_logits`` hands back the logits the first token was
+    picked from (requests without it keep none), and ``lower_steps``
+    lowers the engine's own decode (and chunk) step."""
+    api, params = _api_params("bf16")
+    eng = PagedEngine(
+        api, params, n_slots=2, max_len=MAX_LEN, page_size=PS,
+        chunked_prefill=chunked, prefill_chunk=PS,
+    )
+    p1, p2 = _prompts((11, 6))
+    kept = Request(rid=0, prompt=p1, max_new=2, keep_prompt_logits=True)
+    plain = Request(rid=1, prompt=p2, max_new=2)
+    eng.submit(kept)
+    eng.submit(plain)
+    eng.run_to_completion()
+    want = api.prefill_fn(params, {"tokens": jnp.asarray(p1)[None]}, MAX_LEN)[0][0, -1]
+    assert kept.prompt_logits.shape == (CFG.vocab,)
+    assert kept.prompt_logits.dtype == np.float32
+    assert int(kept.prompt_logits.argmax()) == kept.out[0]
+    np.testing.assert_allclose(kept.prompt_logits, np.asarray(want), rtol=1e-5, atol=1e-5)
+    assert plain.prompt_logits is None
+    lowered = eng.lower_steps()
+    assert set(lowered) == ({"decode", "chunk"} if chunked else {"decode"})
+    for step in lowered.values():
+        assert step.compile().as_text()

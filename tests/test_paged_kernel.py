@@ -155,21 +155,27 @@ def test_null_heavy_tables_skip_null_page_reads():
 
 
 def test_mxu_onehot_page_dequant_bitwise_exact():
-    """The one-hot·codebook MXU dequant of a bcq4 page is a bit-exact table
-    lookup: identical bytes-in → identical f32 out vs the reference
-    flat-gather (the one-hot row has a single 1.0; everything else
-    contributes an exact 0.0)."""
-    from repro.kernels.common import onehot_decode
+    """The in-kernel bcq4 page dequant (lane-repeat nibble unpack +
+    lane-gather codeword lookup, ``common.dequant_page``) is bit-exact
+    against the reference flat-gather dequant of the same page bytes."""
+    from repro.kernels.common import codebook_lookup, dequant_page, flat_codebook
 
+    pool = _pool("bcq4")
+    page = 3
+    refs = [pool[n][page : page + 1] for n in ("k_idx", "k_sel", "k_scale")]
+    got = dequant_page("bcq4", refs, CFG, flat_codebook(CB), pool["k_sx"])
+    want = kref._dequant_pool_ref(dict(pool, _cb=CB), "k", "bcq4", CFG)[page]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want.swapaxes(0, 1)))
+    # every combined codeword of every codebook decodes to its table entry
     rng = np.random.default_rng(0)
-    ne = CFG.n_entries
     code = jnp.asarray(
-        rng.integers(0, CFG.n_codebooks * ne, size=(PS * HKV, D)), jnp.int32
+        rng.integers(0, CFG.n_codebooks * CFG.n_entries, size=(PS * HKV, 3 * D)),
+        jnp.int32,
     )
-    cb_flat = CB.astype(jnp.float32).reshape(-1, 1)
-    got = onehot_decode(code, cb_flat)
-    ref = CB.astype(jnp.float32).reshape(-1)[code]
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    np.testing.assert_array_equal(
+        np.asarray(codebook_lookup(code, flat_codebook(CB))),
+        np.asarray(CB.astype(jnp.float32).reshape(-1)[code]),
+    )
 
 
 def test_model_paged_gather_matches_kernel():
@@ -186,25 +192,3 @@ def test_model_paged_gather_matches_kernel():
     ref = jnp.einsum("bht,bthd->bhd", p, jnp.repeat(vf, 2, 2))
     got = paged_attention(q, pool, bt, lengths, "bcq4", CFG, CB, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("kind", ("bf16", "int8", "bcq4"))
-def test_double_buffered_dma_bitwise_identical(kind):
-    """The hand-rolled two-slot page-DMA path (double_buffer=True: ANY
-    memory-space leaves, make_async_copy prefetching step t+1's page
-    while t computes) is BITWISE identical to the BlockSpec auto-pipeline
-    — ragged lengths, GQA, and a single-page sequence included."""
-    pool = _pool(kind)
-    rng = np.random.default_rng(2)
-    bt = jnp.asarray(rng.integers(0, P, (3, 3)), jnp.int32)
-    lengths = jnp.asarray([1, 17, 24], jnp.int32)
-    q = jax.random.normal(jax.random.PRNGKey(7), (3, 4, D))
-    auto = paged_attention(
-        q, pool, bt, lengths, kind, CFG, CB, interpret=True,
-        double_buffer=False,
-    )
-    manual = paged_attention(
-        q, pool, bt, lengths, kind, CFG, CB, interpret=True,
-        double_buffer=True,
-    )
-    np.testing.assert_array_equal(np.asarray(manual), np.asarray(auto))

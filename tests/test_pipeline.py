@@ -20,8 +20,9 @@ def _run(script, n_dev=4, timeout=420):
 def test_pipeline_matches_sequential_4dev():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.runtime.pipeline import pipeline_apply, bubble_fraction
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         S, D = 4, 16
         keys = jax.random.split(jax.random.PRNGKey(0), S)
         stage_params = {"w": jnp.stack([jax.random.normal(k, (D, D)) * 0.3 for k in keys])}
@@ -44,8 +45,9 @@ def test_pipeline_matches_sequential_4dev():
 def test_pipeline_differentiable_4dev():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.runtime.pipeline import pipeline_apply
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         S, D = 4, 8
         stage_params = {"w": jnp.stack([jnp.eye(D) * 0.9 for _ in range(S)])}
         def stage_fn(p, x):

@@ -15,6 +15,8 @@ from repro.launch.quantize import quantize_checkpoint
 from repro.models import zoo
 from repro.models.layers import Runtime
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 RT = Runtime(quant_mode="none", compute_dtype=jnp.float32, param_dtype=jnp.float32)
 
 
@@ -46,13 +48,13 @@ def test_quantize_cli_end_to_end(tmp_path):
         [sys.executable, "-m", "repro.launch.train", "--arch", "gpt3_126m", "--smoke",
          "--steps", "5", "--batch", "2", "--seq", "32", "--ckpt", str(ck),
          "--save-every", "5", "--log-every", "5"],
-        capture_output=True, text=True, env=env, cwd="/root/repo", timeout=400,
+        capture_output=True, text=True, env=env, cwd=REPO, timeout=400,
     )
     assert r1.returncode == 0, r1.stderr[-1500:]
     r2 = subprocess.run(
         [sys.executable, "-m", "repro.launch.quantize", "--ckpt", str(ck),
          "--arch", "gpt3_126m", "--smoke", "--out", str(out)],
-        capture_output=True, text=True, env=env, cwd="/root/repo", timeout=400,
+        capture_output=True, text=True, env=env, cwd=REPO, timeout=400,
     )
     assert r2.returncode == 0, r2.stderr[-1500:]
     man = json.load(open(out / "manifest.json"))

@@ -19,6 +19,8 @@ from repro.models import zoo
 from repro.models.layers import Runtime
 from repro.optim import adamw
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 RT = Runtime(quant_mode="none", compute_dtype=jnp.float32, param_dtype=jnp.float32)
 
 
@@ -99,9 +101,9 @@ def test_train_cli_resume(tmp_path):
         "--save-every", "10", "--log-every", "10", "--ckpt", str(tmp_path),
     ]
     r1 = subprocess.run(base + ["--steps", "12"], capture_output=True, text=True,
-                        env=env, cwd="/root/repo", timeout=500)
+                        env=env, cwd=REPO, timeout=500)
     assert r1.returncode == 0, r1.stderr[-2000:]
     r2 = subprocess.run(base + ["--steps", "30"], capture_output=True, text=True,
-                        env=env, cwd="/root/repo", timeout=500)
+                        env=env, cwd=REPO, timeout=500)
     assert r2.returncode == 0, r2.stderr[-2000:]
     assert "resumed from step" in r2.stdout, r2.stdout
