@@ -108,7 +108,11 @@ that keeps depth 2 bit-identical to the legacy synchronous loop
   demote identical requests at every depth (docs/ROBUSTNESS.md,
   "Quarantine under the pipelined tick loop").
 
-Telemetry splits attribution at depth 2: ``decode_tick_s`` holds the
+Each tick's regions are ``Telemetry.span``s — ``engine_step`` around
+``step()``, ``admit``, ``prefill_launch`` (the chunk launch),
+``decode_tick`` (the decode launch) and ``decode_sync`` — so they land
+on the profiler's host timeline while a trace runs.  Telemetry splits
+attribution at depth 2: ``decode_tick_s`` holds the
 dispatch-only launch span, ``decode_sync_s`` the blocking fetch, and
 ``decode_host_gap_s`` the between-launch host gap on quiet ticks —
 the pipeline's figure of merit (BENCH_paged.json gates on
@@ -147,6 +151,7 @@ one poisoned request cannot take the batch down or leak pages:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -305,7 +310,6 @@ class _InFlight:
     rows: list  # (slot_idx, req, pos_after_launch) triples
     nxt: object  # (n_slots,) device int32 — merged greedy/sampled tokens
     fin: object  # (n_slots,) device bool finite mask; None with guard off
-    n_active: int
 
 
 class PagedEngine:
@@ -1296,14 +1300,17 @@ class PagedEngine:
                 self.faults.delay_launch(self._tick, key=0)
             t0 = time.perf_counter()
             self.telemetry.on_admit(req, t0)
-            logits, cache1 = self._prefill(self.params, tokens)
-            logits = jax.block_until_ready(logits)
+            with self.telemetry.span(
+                "prefill_launch", slots=1, tokens=plen, tick=self._tick,
+                rows_launched=1, chunk_bucket=plen, synced=True,
+            ):
+                logits, cache1 = self._prefill(self.params, tokens)
+                logits = jax.block_until_ready(logits)
             self._c_syncs.inc()
             t1 = time.perf_counter()
             self._c["t_prefill_s"].inc(t1 - t0)
             self._c["prefill_launches"].inc()
-            self.telemetry.prefill_launch(t0, t1, slots=1, tokens=plen)
-            self.telemetry.on_chunk(req, t0, t1, plen)  # whole prompt, 1 chunk
+            self.telemetry.on_chunk(req, t1, plen)  # whole prompt, 1 chunk
             self.pool = self._scatter(self.pool, cache1, jnp.asarray(scatter_ids))
             if self.prefix_caching:
                 for i in range(n_claimed, n_full):
@@ -1398,52 +1405,55 @@ class PagedEngine:
 
     def _admit(self) -> int:
         admitted = 0
-        while self.queue:
-            free = [
-                i for i, s in enumerate(self.slots)
-                if s.req is None and s.reserved_by is None
-            ]
-            req = self.queue[0]
-            if not free or req.n_samples > len(free):
-                break  # head-of-line waits for a slot (or n sibling slots)
-            try:
-                ok = self._try_admit(req, free[0])
-            except Exception as exc:
-                if self.strict:
-                    raise
-                # containment: admission blew up mid-flight (injected alloc
-                # flake, exhaustion the watermark should have prevented, a
-                # poisoned prefill).  _try_admit already rolled its page
-                # claims back; retry a transient failure a few times from
-                # the head, then fail the REQUEST instead of the loop.
-                self.queue.popleft()
-                if isinstance(exc, pages_lib.PageCorruptionError):
-                    # NO retry: a retry would succeed via recompute and
-                    # mask the integrity failure — quarantine the owner
-                    # (only this request ever referenced the bad bytes)
-                    self._finish_error(
-                        req, "quarantined",
-                        f"swap-in integrity failure: {exc}",
-                    )
+        with self.telemetry.span("admit") as args:
+            while self.queue:
+                free = [
+                    i for i, s in enumerate(self.slots)
+                    if s.req is None and s.reserved_by is None
+                ]
+                req = self.queue[0]
+                if not free or req.n_samples > len(free):
+                    break  # head-of-line waits for a slot (or n sibling slots)
+                try:
+                    ok = self._try_admit(req, free[0])
+                except Exception as exc:
+                    if self.strict:
+                        raise
+                    # containment: admission blew up mid-flight (injected alloc
+                    # flake, exhaustion the watermark should have prevented, a
+                    # poisoned prefill).  _try_admit already rolled its page
+                    # claims back; retry a transient failure a few times from
+                    # the head, then fail the REQUEST instead of the loop.
+                    self.queue.popleft()
+                    if isinstance(exc, pages_lib.PageCorruptionError):
+                        # NO retry: a retry would succeed via recompute and
+                        # mask the integrity failure — quarantine the owner
+                        # (only this request ever referenced the bad bytes)
+                        self._finish_error(
+                            req, "quarantined",
+                            f"swap-in integrity failure: {exc}",
+                        )
+                        break
+                    req._admit_retries += 1
+                    if req._admit_retries <= 3:
+                        self.queue.appendleft(req)
+                        self.telemetry.instant(
+                            "admit_retry", rid=int(req.rid),
+                            attempt=req._admit_retries,
+                        )
+                    else:
+                        self._finish_error(
+                            req, "quarantined",
+                            f"admission failed after {req._admit_retries - 1} "
+                            f"retries: {type(exc).__name__}: {exc}",
+                        )
                     break
-                req._admit_retries += 1
-                if req._admit_retries <= 3:
-                    self.queue.appendleft(req)
-                    self.telemetry.instant(
-                        "admit_retry", rid=int(req.rid),
-                        attempt=req._admit_retries,
-                    )
-                else:
-                    self._finish_error(
-                        req, "quarantined",
-                        f"admission failed after {req._admit_retries - 1} "
-                        f"retries: {type(exc).__name__}: {exc}",
-                    )
-                break
-            if not ok:
-                break  # admission control: head-of-line blocks until pages free
-            self.queue.popleft()
-            admitted += 1
+                if not ok:
+                    break  # admission control: head-of-line blocks until pages free
+                self.queue.popleft()
+                admitted += 1
+            args["admitted"] = admitted
+            args["queue"] = len(self.queue)
         return admitted
 
     def _start_decode(self, i: int, logits) -> None:
@@ -1755,39 +1765,43 @@ class PagedEngine:
         n_cp_b = pages_needed(c_bucket, self.ps)
         bb = _pow2_bucket(len(batch), self.n_slots)
         w = self.tables.shape[1]
-        # one packed int32 staging array → ONE host→device transfer per
-        # chunk tick (tokens | n_past | scatter ids | chunk_len | table);
-        # NULL_PAGE == 0, so zero-init doubles as the id/table padding
-        packed = np.zeros((bb, c_bucket + 2 + n_cp_b + w), np.int32)
-        for r, i in enumerate(batch):
-            start, c, ids = plans[i]
-            packed[r, :c] = self.slots[i].pending[start : start + c]
-            packed[r, c_bucket] = start
-            packed[r, c_bucket + 1 : c_bucket + 1 + len(ids)] = ids
-            packed[r, c_bucket + 1 + n_cp_b] = c
-            packed[r, c_bucket + 2 + n_cp_b :] = self.tables[i]
+        # a slot finishes its prompt: the logits are consumed on host
+        # right below, so syncing them is free — and it makes the timing
+        # split exact for exactly the ticks that produce tokens.
+        # Mid-prompt ticks skip the sync to keep host/device overlap
+        # unless profile_sync asks for an exact split.
+        synced = self.profile_sync or any(
+            plans[i][0] + plans[i][1] == len(self.slots[i].pending) for i in batch
+        )
         if self.faults is not None:
             self.faults.delay_launch(self._tick, key=2)
-        t0 = time.perf_counter()
-        logits, self.pool = self._chunk_step(
-            self.params, jnp.asarray(packed), c_bucket, n_cp_b
-        )
-        if self.profile_sync or any(
-            plans[i][0] + plans[i][1] == len(self.slots[i].pending) for i in batch
+        with self.telemetry.span(
+            "prefill_launch", slots=len(batch),
+            tokens=int(sum(plans[i][1] for i in batch)), tick=self._tick,
+            rows_launched=bb, chunk_bucket=c_bucket, synced=bool(synced),
         ):
-            # a slot finishes its prompt: the logits are consumed on host
-            # right below, so this sync is free — and it makes the timing
-            # split exact for exactly the ticks that produce tokens.
-            # Mid-prompt ticks skip the sync to keep host/device overlap
-            # unless profile_sync asks for an exact split.
-            logits = jax.block_until_ready(logits)
-            self._c_syncs.inc()
-        t1 = time.perf_counter()
+            # one packed int32 staging array → ONE host→device transfer
+            # per chunk tick (tokens | n_past | scatter ids | chunk_len |
+            # table); NULL_PAGE == 0, so zero-init doubles as the id/table
+            # padding
+            packed = np.zeros((bb, c_bucket + 2 + n_cp_b + w), np.int32)
+            for r, i in enumerate(batch):
+                start, c, ids = plans[i]
+                packed[r, :c] = self.slots[i].pending[start : start + c]
+                packed[r, c_bucket] = start
+                packed[r, c_bucket + 1 : c_bucket + 1 + len(ids)] = ids
+                packed[r, c_bucket + 1 + n_cp_b] = c
+                packed[r, c_bucket + 2 + n_cp_b :] = self.tables[i]
+            t0 = time.perf_counter()
+            logits, self.pool = self._chunk_step(
+                self.params, jnp.asarray(packed), c_bucket, n_cp_b
+            )
+            if synced:
+                logits = jax.block_until_ready(logits)
+                self._c_syncs.inc()
+            t1 = time.perf_counter()
         self._c["t_prefill_s"].inc(t1 - t0)
         self._c["prefill_launches"].inc()
-        self.telemetry.prefill_launch(
-            t0, t1, slots=len(batch), tokens=int(sum(plans[i][1] for i in batch))
-        )
 
         for r, i in enumerate(batch):
             start, c, _ = plans[i]
@@ -1795,7 +1809,7 @@ class PagedEngine:
             slot.pos = start + c
             self._c["prefill_chunks"].inc()
             self._c["prefill_tokens"].inc(c)
-            self.telemetry.on_chunk(slot.req, t0, t1, c)
+            self.telemetry.on_chunk(slot.req, t1, c)
             if self.prefix_caching:
                 first_page = start // self.ps
                 for p in range(first_page, min(slot.pos // self.ps, len(slot.hashes))):
@@ -1863,8 +1877,6 @@ class PagedEngine:
         for i in active:
             pk[i, 2] = self.slots[i].pos
             pk[i, 3:] = self.tables[i]
-        if self.faults is not None:
-            self.faults.delay_launch(self._tick, key=1)
         t0 = time.perf_counter()
         if quiet and self._last_launch_end is not None:
             # steady-state host gap: launch-to-launch wall clock minus the
@@ -1902,16 +1914,15 @@ class PagedEngine:
             self._chained[i] = True
         self._chain_tok = nxt
         self._inflight.append(
-            _InFlight(self._tick, rows, nxt, fin, len(active))
+            _InFlight(self._tick, rows, nxt, fin)
         )
         t1 = time.perf_counter()
         self._c["decode_ticks"].inc()
         self.telemetry.pipeline_gauge(len(self._inflight))
         if self.pipeline_depth > 1:
-            # depth 1 defers span accounting to the merged sync (legacy
+            # depth 1 defers the time to the merged sync (legacy
             # attribution); deep mode attributes dispatch and sync apart
             self._c["t_decode_s"].inc(t1 - t0)
-            self.telemetry.decode_tick(t0, t1, n_active=len(active))
         self._last_launch_end = t1
         self._gap_sync_s = 0.0
         return t0
@@ -1921,22 +1932,23 @@ class PagedEngine:
         EOS-retire / quarantine per row, exactly the bookkeeping the
         synchronous loop did — one tick later at depth 2, without changing
         which request gets demoted (fault seams key on the launch tick).
-        ``merge_from`` (depth 1) folds the wait into the launch span so
-        profile-mode attribution matches the legacy loop exactly."""
+        ``merge_from`` (depth 1) folds the wait into the launch's time,
+        and the caller's ``decode_tick`` span covers both, so profile-mode
+        attribution matches the legacy loop exactly; otherwise the wait is
+        its own ``decode_sync`` span."""
         rec = self._inflight.popleft()
-        t0 = time.perf_counter()
-        nxt = np.asarray(rec.nxt)  # blocks until the launch drains
-        # copy: the mask is mutated by injected logits poisoning
-        fin = None if rec.fin is None else np.array(rec.fin)
-        self._c_syncs.inc()
-        t1 = time.perf_counter()
+        with (
+            contextlib.nullcontext() if merge_from is not None
+            else self.telemetry.span("decode_sync", tick=rec.tick)
+        ):
+            t0 = time.perf_counter()
+            nxt = np.asarray(rec.nxt)  # blocks until the launch drains
+            # copy: the mask is mutated by injected logits poisoning
+            fin = None if rec.fin is None else np.array(rec.fin)
+            self._c_syncs.inc()
+            t1 = time.perf_counter()
         self._gap_sync_s += t1 - t0
-        if merge_from is not None:
-            self._c["t_decode_s"].inc(t1 - merge_from)
-            self.telemetry.decode_tick(merge_from, t1, n_active=rec.n_active)
-        else:
-            self._c["t_decode_s"].inc(t1 - t0)
-            self.telemetry.decode_sync(t0, t1, tick=rec.tick)
+        self._c["t_decode_s"].inc(t1 - (t0 if merge_from is None else merge_from))
         cap = self._seq_capacity() if self.chunked else self.max_len
         # slots with a NEWER launch still in flight: their freshest token
         # lives in _chain_tok, so booking this (older) token must NOT
@@ -2015,30 +2027,44 @@ class PagedEngine:
         pipeline — the device is idle anyway, and slots waiting on their
         final sync must retire for admission to reuse them."""
         self._tick += 1
-        self._enforce_lifecycle()
-        self._update_pressure()
-        admitted = self._admit()
-        served = self._prefill_tick_all()
+        with self.telemetry.span("engine_step", tick=self._tick):
+            self._enforce_lifecycle()
+            self._update_pressure()
+            admitted = self._admit()
+            served = self._prefill_tick_all()
 
-        active = []
-        for i in self._decoding():
-            if self._retire_pending(i):
-                continue  # retires at its pending sync below
-            if self._ensure_tail_page(i):
-                active.append(i)
-        active = [i for i in active if self.slots[i].req is not None
-                  and self.slots[i].mode == "decode"]
-        if active:
-            t0 = self._launch_decode(
-                active, quiet=(served == 0 and admitted == 0)
-            )
-            while len(self._inflight) >= self.pipeline_depth:
-                self._sync_one(t0 if len(self._inflight) == 1 else None)
-        else:
-            self.drain()
-        if self.audit_every and self._tick % self.audit_every == 0:
-            self.audit()
+            active = []
+            for i in self._decoding():
+                if self._retire_pending(i):
+                    continue  # retires at its pending sync below
+                if self._ensure_tail_page(i):
+                    active.append(i)
+            active = [i for i in active if self.slots[i].req is not None
+                      and self.slots[i].mode == "decode"]
+            if active:
+                self._decode_tick(active, quiet=(served == 0 and admitted == 0))
+            else:
+                self.drain()
+            if self.audit_every and self._tick % self.audit_every == 0:
+                self.audit()
         return served + len(active)
+
+    def _decode_tick(self, active: list, *args, **kwargs) -> None:
+        """``_launch_decode(active, *args, **kwargs)`` inside the
+        ``decode_tick`` span, then sync while the pipeline is full.  At
+        depth 1 the span also covers the merged sync (legacy
+        attribution); deeper, each sync is its own ``decode_sync``."""
+        if self.faults is not None:
+            self.faults.delay_launch(self._tick, key=1)
+        with self.telemetry.span(
+            "decode_tick", n_active=len(active), tick=self._tick,
+            rows_launched=self.n_slots,
+        ):
+            t0 = self._launch_decode(active, *args, **kwargs)
+            if self.pipeline_depth == 1:
+                self._sync_one(t0)
+        while len(self._inflight) >= self.pipeline_depth:
+            self._sync_one()
 
     def run_to_completion(self, max_ticks: int = 10_000):
         """Tick until the queue and the slots drain (or max_ticks).  A

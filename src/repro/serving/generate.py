@@ -200,7 +200,10 @@ def api_jit(api, key, fn):
     engine instances stop recompiling N times.  Each cached entry is
     ``(jitted_fn, {"traces": n})`` — the wrapped python body runs once per
     jit trace, which is the measurable contract behind the serving-shape
-    bucketing policy (see ``PagedEngine.trace_counts``)."""
+    bucketing policy (see ``PagedEngine.trace_counts``).  The jitted
+    function is named after ``key[0]`` (``paged_decode_fused``,
+    ``chunk_step``, ``prefill``, ...), so each step program carries its
+    key's name in a profile (``jit_paged_decode_fused(...)``)."""
     cache = getattr(api, "_engine_jit_cache", None)
     if cache is None:
         cache = {}
@@ -212,6 +215,7 @@ def api_jit(api, key, fn):
             _c["traces"] += 1  # python body runs once per jit trace
             return _fn(*args)
 
+        counted.__name__ = counted.__qualname__ = str(key[0])
         cache[key] = (jax.jit(counted), counts)
     return cache[key]
 

@@ -579,45 +579,49 @@ class StatePagedEngine(PagedEngine):
                 self.faults.delay_launch(self._tick, key=0)
             t0 = time.perf_counter()
             self.telemetry.on_admit(req, t0)
-            if resume is not None:
-                # bounded replay: restore the checkpoint, replay only the
-                # tokens past it (≤ page_size by the boundary-checkpoint
-                # cadence), batch-1 through the same per-row decode fn
-                pid, cpos = int(resume[0]), int(resume[1])
-                one = self._restore_one(self.spool, jnp.int32(pid))
-                self._cs["state_restores"].inc()
-                logits = None
-                for k in range(cpos, plen):
-                    t = jnp.asarray(prompt[k : k + 1], jnp.int32)[None]
-                    args = (self.params, one, t, jnp.int32(k))
-                    if self.shared_enc:
-                        args += (self.enc_pool, jnp.asarray([enc_page], jnp.int32))
-                    logits, one = self._replay_step(*args)
-                assert logits is not None, "checkpoint at/past prompt end"
-                n_replayed = plen - cpos
-                self._cs["replay_tokens"].inc(n_replayed)
-                ckpt_page, ckpt_pos = pid, cpos
-                req._state_resume = None  # ref now owned by the slot
-                acquired.append(pid)
-            else:
-                tokens = jnp.asarray(prompt, jnp.int32)[None, :]
-                if self.shared_enc:
-                    logits, caches = self._prefill_xkv(
-                        self.params, tokens, self.enc_pool, jnp.int32(enc_page)
-                    )
-                    one = {"self": caches}
+            with self.telemetry.span(
+                "prefill_launch", slots=1, tick=self._tick, rows_launched=1,
+                synced=True,
+            ) as span_args:
+                if resume is not None:
+                    # bounded replay: restore the checkpoint, replay only the
+                    # tokens past it (≤ page_size by the boundary-checkpoint
+                    # cadence), batch-1 through the same per-row decode fn
+                    pid, cpos = int(resume[0]), int(resume[1])
+                    one = self._restore_one(self.spool, jnp.int32(pid))
+                    self._cs["state_restores"].inc()
+                    logits = None
+                    for k in range(cpos, plen):
+                        t = jnp.asarray(prompt[k : k + 1], jnp.int32)[None]
+                        args = (self.params, one, t, jnp.int32(k))
+                        if self.shared_enc:
+                            args += (self.enc_pool, jnp.asarray([enc_page], jnp.int32))
+                        logits, one = self._replay_step(*args)
+                    assert logits is not None, "checkpoint at/past prompt end"
+                    n_replayed = plen - cpos
+                    self._cs["replay_tokens"].inc(n_replayed)
+                    ckpt_page, ckpt_pos = pid, cpos
+                    req._state_resume = None  # ref now owned by the slot
+                    acquired.append(pid)
                 else:
-                    logits, one = self._prefill(self.params, tokens)
-                n_replayed = plen
-                ckpt_page, ckpt_pos = None, 0
-            logits = jax.block_until_ready(logits)
-            self._c_syncs.inc()
-            t1 = time.perf_counter()
+                    tokens = jnp.asarray(prompt, jnp.int32)[None, :]
+                    if self.shared_enc:
+                        logits, caches = self._prefill_xkv(
+                            self.params, tokens, self.enc_pool, jnp.int32(enc_page)
+                        )
+                        one = {"self": caches}
+                    else:
+                        logits, one = self._prefill(self.params, tokens)
+                    n_replayed = plen
+                    ckpt_page, ckpt_pos = None, 0
+                span_args["tokens"] = span_args["chunk_bucket"] = n_replayed
+                logits = jax.block_until_ready(logits)
+                self._c_syncs.inc()
+                t1 = time.perf_counter()
             self._c["t_prefill_s"].inc(t1 - t0)
             self._c["prefill_launches"].inc()
             self._c["prefill_tokens"].inc(n_replayed)
-            self.telemetry.prefill_launch(t0, t1, slots=1, tokens=n_replayed)
-            self.telemetry.on_chunk(req, t0, t1, n_replayed)
+            self.telemetry.on_chunk(req, t1, n_replayed)
 
             self.live = self._insert_row(self.live, one, jnp.int32(slot_idx))
             if ckpt_page is None:
@@ -783,8 +787,6 @@ class StatePagedEngine(PagedEngine):
             pk[i, 3] = dsts[i]
             if s.enc_page is not None:
                 pk[i, 4] = s.enc_page
-        if self.faults is not None:
-            self.faults.delay_launch(self._tick, key=1)
         t0 = time.perf_counter()
         if quiet and self._last_launch_end is not None:
             self.telemetry.decode_gap(
@@ -812,13 +814,12 @@ class StatePagedEngine(PagedEngine):
             rows.append((i, slot.req, slot.pos))
             self._chained[i] = True
         self._chain_tok = nxt
-        self._inflight.append(_InFlight(self._tick, rows, nxt, fin, len(active)))
+        self._inflight.append(_InFlight(self._tick, rows, nxt, fin))
         t1 = time.perf_counter()
         self._c["decode_ticks"].inc()
         self.telemetry.pipeline_gauge(len(self._inflight))
         if self.pipeline_depth > 1:
             self._c["t_decode_s"].inc(t1 - t0)
-            self.telemetry.decode_tick(t0, t1, n_active=len(active))
         self._last_launch_end = t1
         self._gap_sync_s = 0.0
         return t0
@@ -829,27 +830,26 @@ class StatePagedEngine(PagedEngine):
         scatter in the same launch.  Pipelining semantics (depth 1 vs 2,
         speculative EOS rows, drain-on-idle) are inherited unchanged."""
         self._tick += 1
-        self._enforce_lifecycle()
-        self._update_pressure()
-        admitted = self._admit()
+        with self.telemetry.span("engine_step", tick=self._tick):
+            self._enforce_lifecycle()
+            self._update_pressure()
+            admitted = self._admit()
 
-        dsts = np.full((self.n_slots,), NULL_PAGE, np.int32)
-        active = []
-        for i in self._decoding():
-            if self._retire_pending(i):
-                continue  # retires at its pending sync below
-            if (self.slots[i].pos + 1) % self.ps == 0:
-                dsts[i] = self._ensure_private_ckpt(i)
-            active.append(i)
-        active = [i for i in active if self.slots[i].req is not None]
-        if active:
-            t0 = self._launch_decode(active, dsts, quiet=(admitted == 0))
-            while len(self._inflight) >= self.pipeline_depth:
-                self._sync_one(t0 if len(self._inflight) == 1 else None)
-        else:
-            self.drain()
-        if self.audit_every and self._tick % self.audit_every == 0:
-            self.audit()
+            dsts = np.full((self.n_slots,), NULL_PAGE, np.int32)
+            active = []
+            for i in self._decoding():
+                if self._retire_pending(i):
+                    continue  # retires at its pending sync below
+                if (self.slots[i].pos + 1) % self.ps == 0:
+                    dsts[i] = self._ensure_private_ckpt(i)
+                active.append(i)
+            active = [i for i in active if self.slots[i].req is not None]
+            if active:
+                self._decode_tick(active, dsts, quiet=(admitted == 0))
+            else:
+                self.drain()
+            if self.audit_every and self._tick % self.audit_every == 0:
+                self.audit()
         return len(active)
 
     def health(self) -> dict:

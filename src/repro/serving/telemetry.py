@@ -14,7 +14,7 @@ Three layers, all host-side and sync-free at the default level:
   finish, with preemption/resubmission folded into the SAME timeline (a
   preempted-and-resumed request reports one submit, two admits, and a
   TTFT measured from its original submit).  Forked siblings get
-  independent timelines that share the parent's prefill span list.
+  independent timelines that start from a copy of the parent's chunks.
   Observations feed the TTFT / ITL (TPOT) / queue-time histograms.
 
 * **QuantProbeSink** — opt-in (``Runtime.quant_probe``): the LO-BCQ
@@ -22,6 +22,13 @@ Three layers, all host-side and sync-free at the default level:
   occupancy via ``jax.debug.callback``; the sink attributes them to
   layers by arrival order (each site fires once per layer per launch, in
   ``lax.scan`` iteration order) and aggregates per (site, layer).
+
+The engine's tick regions (``engine_step``, ``admit``, the launch and
+sync spans) go through one API, :meth:`Telemetry.span`: at the default
+level each region is a ``jax.profiler.TraceAnnotation`` of the same name
+(so it lands on the profiler's host timeline when a trace is running),
+a journal span with its arguments, and an observation of the histogram
+bound to its name.
 
 Timestamps everywhere are ``time.perf_counter()`` seconds.  All
 histogram bucket layouts are module-level constants — tests pin them, and
@@ -31,14 +38,16 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import time
 from bisect import bisect_right
 from collections import deque
 from collections.abc import Mapping
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from repro.serving.events import TID_HOST, TraceJournal
+from repro.serving.events import TID_DEVICE, TID_HOST, TraceJournal
 
 SCHEMA_VERSION = 1
 
@@ -230,8 +239,8 @@ class RequestTimeline:
 
     Preemption re-queues the request onto the SAME timeline (``admits``
     grows, ``t_submit`` stays), so derived TTFT spans the preemption.
-    Forked siblings each get their own timeline; ``prefill_spans`` is the
-    *shared* parent list (the siblings rode one prefill)."""
+    Forked siblings each get their own timeline, starting from a copy of
+    the parent's ``chunks`` (the siblings rode one prefill)."""
 
     rid: int
     sample_idx: int = 0
@@ -240,7 +249,6 @@ class RequestTimeline:
     admits: list = dataclasses.field(default_factory=list)
     # (t_end, n_tokens) per prefill chunk this request advanced through
     chunks: list = dataclasses.field(default_factory=list)
-    prefill_spans: list = dataclasses.field(default_factory=list)
     t_first: Optional[float] = None
     t_last_tok: Optional[float] = None
     t_finish: Optional[float] = None
@@ -266,6 +274,57 @@ class RequestTimeline:
             "ttft_s": self.ttft(), "tpot_s": self.tpot(),
             "t_finish": self.t_finish,
         }
+
+
+# ------------------------------------------------------------- tick spans
+# journal thread of each engine span: the tick and admission regions on
+# the host-scheduling track, every launch / sync span on the launch track
+SPAN_TIDS = {"engine_step": TID_HOST, "admit": TID_HOST}
+
+
+class _Span:
+    """One default-level span (see :meth:`Telemetry.span`)."""
+
+    __slots__ = ("tel", "name", "args", "ann", "t0")
+
+    def __init__(self, tel, name: str, args: dict):
+        self.tel = tel
+        self.name = name
+        self.args = args
+
+    def __enter__(self) -> dict:
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self.args
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.perf_counter()
+        self.ann.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            tel = self.tel
+            h = tel._span_hist.get(self.name)
+            if h is not None:
+                h.observe(t1 - self.t0)
+            tel.journal.span(self.name, self.t0, t1,
+                             tid=SPAN_TIDS.get(self.name, TID_DEVICE),
+                             args=self.args or None)
+        return False
+
+
+class _NoSpan:
+    """The counters-level span: records nothing, yields a scratch dict."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> dict:
+        return {}
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
 
 
 # --------------------------------------------------------------- telemetry
@@ -310,6 +369,11 @@ class Telemetry:
             "decode_host_gap_s", LAUNCH_BUCKETS, "s")
         self.g_inflight = self.registry.gauge(
             "pipeline_inflight", "launches")
+        self._span_hist = {
+            "prefill_launch": self.h_prefill,
+            "decode_tick": self.h_decode,
+            "decode_sync": self.h_decode_sync,
+        }
 
     # ------------------------------------------------- request lifecycle
     def _timeline(self, req) -> Optional[RequestTimeline]:
@@ -335,15 +399,14 @@ class Telemetry:
         tl.admits.append(now)
         self.h_queue.observe(now - tl.t_enqueued)
 
-    def on_chunk(self, req, t0: float, t1: float, n_tokens: int) -> None:
-        """One prefill chunk advanced this request (t0/t1 = the launch
-        span it rode; non-chunked admission reports the whole prompt as
-        one chunk)."""
+    def on_chunk(self, req, t_end: float, n_tokens: int) -> None:
+        """One prefill chunk advanced this request (``t_end`` = the end
+        of the launch it rode, one value for every row of that launch;
+        non-chunked admission reports the whole prompt as one chunk)."""
         tl = self._timeline(req)
         if tl is None:
             return
-        tl.chunks.append((t1, int(n_tokens)))
-        tl.prefill_spans.append((t0, t1))
+        tl.chunks.append((t_end, int(n_tokens)))
 
     def on_first_token(self, req, now: float) -> None:
         tl = self._timeline(req)
@@ -385,9 +448,9 @@ class Telemetry:
 
     def on_fork_child(self, parent, child, now: float) -> None:
         """An independent timeline for a forked sibling: same submit /
-        admit history (the sibling existed implicitly since submission),
-        SHARED prefill-span list (one prefill served all siblings), own
-        token timing from here on."""
+        admit / chunk history (the sibling existed implicitly since
+        submission, and one prefill served all siblings), own token
+        timing from here on."""
         ptl = self._timeline(parent)
         if not self.detailed or ptl is None:
             return
@@ -395,34 +458,27 @@ class Telemetry:
             rid=child.rid, sample_idx=child.sample_idx,
             t_submit=ptl.t_submit, t_enqueued=ptl.t_enqueued,
             admits=list(ptl.admits), chunks=list(ptl.chunks),
-            prefill_spans=ptl.prefill_spans,  # shared by design
         )
         if len(self.timelines) == self.timelines.maxlen:
             self._c_tl_dropped.inc()
         self.timelines.append(child.timeline)
 
     # ------------------------------------------------------- tick spans
-    def prefill_launch(self, t0: float, t1: float, **args) -> None:
-        if not self.detailed:
-            return
-        self.h_prefill.observe(t1 - t0)
-        self.journal.span("prefill_launch", t0, t1, args=args or None)
+    def span(self, name: str, **args):
+        """A context manager around one region of the tick loop.
 
-    def decode_tick(self, t0: float, t1: float, **args) -> None:
+        At ``"default"`` it enters ``jax.profiler.TraceAnnotation(name)``
+        (the name alone, so event names in a profile stay stable), times
+        the region with ``perf_counter``, and on a normal exit records a
+        journal span carrying ``args`` (on the :data:`SPAN_TIDS` track)
+        and observes the histogram bound to ``name`` where there is one
+        (``prefill_launch_s``, ``decode_tick_s``, ``decode_sync_s``).
+        It yields the ``args`` dict, so the region can add arguments it
+        only learns inside.  At ``"counters"`` it is one shared no-op
+        context."""
         if not self.detailed:
-            return
-        self.h_decode.observe(t1 - t0)
-        self.journal.span("decode_tick", t0, t1, args=args or None)
-
-    def decode_sync(self, t0: float, t1: float, **args) -> None:
-        """The sync-side wait of a pipelined decode launch (depth > 1):
-        how long the host blocked for the oldest in-flight launch.  With
-        ``profile_sync`` / depth 1 the wait is folded into ``decode_tick``
-        instead (legacy attribution), so this histogram stays empty."""
-        if not self.detailed:
-            return
-        self.h_decode_sync.observe(t1 - t0)
-        self.journal.span("decode_sync", t0, t1, args=args or None)
+            return _NO_SPAN
+        return _Span(self, name, args)
 
     def decode_gap(self, gap: float) -> None:
         """Pure host time between consecutive steady-state decode

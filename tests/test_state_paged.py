@@ -391,3 +391,34 @@ def test_unsupported_family_raises_typed():
 
     # vlm is not paged-servable at all
     assert zoo.build(get_smoke("pixtral_12b"), RT).page_spec is None
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_state_engine_spans(depth):
+    """The state engine's tick goes through the same span API: one
+    ``engine_step`` / ``admit`` per step, one ``decode_tick`` per launch
+    packing all ``n_slots`` rows, and (deeper than 1) one ``decode_sync``
+    per launch; at depth 1 the sync merges into ``decode_tick``."""
+    cfg, api, params = _built("mamba2_130m")
+    eng = StatePagedEngine(
+        api, params, n_slots=4, max_len=ML, page_size=PS, pipeline_depth=depth
+    )
+    rng = np.random.default_rng(3)
+    for i in range(2):
+        eng.submit(Request(
+            rid=i, prompt=rng.integers(0, cfg.vocab, size=S).astype(np.int32),
+            max_new=4,
+        ))
+    _, ticks = eng.run_to_completion()
+    spans = [r for r in eng.telemetry.journal._buf if r[0] == "span"]
+    by = {}
+    for r in spans:
+        by.setdefault(r[1], []).append(r[6])
+    assert len(by["engine_step"]) == len(by["admit"]) == ticks
+    dec = by["decode_tick"]
+    assert len(dec) == eng.stats["decode_ticks"] > 0
+    assert all(a["rows_launched"] == 4 for a in dec)
+    assert len(by.get("decode_sync", [])) == (0 if depth == 1 else len(dec))
+    pre = by["prefill_launch"]
+    assert [a["tokens"] for a in pre] == [S, S]
+    assert all(a["rows_launched"] == 1 and a["chunk_bucket"] == S for a in pre)

@@ -163,8 +163,11 @@ def test_counters_level_hooks_are_noops():
     req = Request(rid=0, prompt=np.zeros(4, np.int32), max_new=2)
     tel.on_submit(req, 1.0)
     assert req.timeline is None and len(tel.timelines) == 0
-    tel.prefill_launch(1.0, 2.0)
-    tel.decode_tick(2.0, 3.0)
+    with tel.span("prefill_launch", tokens=4) as args:
+        args["synced"] = True
+    with tel.span("decode_tick", n_active=1):
+        pass
+    assert tel.span("engine_step") is tel.span("admit")  # one shared no-op
     assert tel.h_prefill.count == 0 and tel.h_decode.count == 0
     assert len(tel.journal) == 0
 
@@ -290,8 +293,8 @@ def test_preemption_timeline_single_submit_two_admits(api_params):
 
 
 def test_fork_timelines_independent_with_shared_prefill(api_params):
-    """Forked siblings: independent timelines (own tokens/TTFT) that share
-    the parent's prefill-span list — one prefill served every sibling."""
+    """Forked siblings: independent timelines (own tokens/TTFT) that start
+    from the parent's chunks — one prefill served every sibling."""
     api, params = api_params
     rng = np.random.default_rng(5)
     prompt = rng.integers(0, CFG.vocab, size=PS + 3).astype(np.int32)
@@ -308,7 +311,7 @@ def test_fork_timelines_independent_with_shared_prefill(api_params):
     assert len(children) == 2
     for ch in children:
         assert ch is not parent
-        assert ch.prefill_spans is parent.prefill_spans  # shared by design
+        assert ch.chunks == parent.chunks and ch.chunks  # one prefill
         assert ch.t_submit == parent.t_submit  # sibling existed at submit
         assert ch.ttft() is not None
     # each sibling decodes its own tokens on its own timeline
@@ -316,3 +319,170 @@ def test_fork_timelines_independent_with_shared_prefill(api_params):
     for tl in tls:
         assert tl.n_tokens == len(out_by_sample[tl.sample_idx])
     assert eng.telemetry.h_ttft.count == 3
+
+
+# ------------------------------------------------------------------ spans
+def _spans(journal, name=None):
+    return [r for r in journal._buf
+            if r[0] == "span" and (name is None or r[1] == name)]
+
+
+@pytest.mark.parametrize("name, hist", [
+    ("prefill_launch", "prefill_launch_s"),
+    ("decode_tick", "decode_tick_s"),
+    ("decode_sync", "decode_sync_s"),
+    ("admit", None),
+])
+def test_span_records_args_and_its_histogram(name, hist):
+    tel = Telemetry()
+    with tel.span(name, tick=3) as args:
+        args["late"] = 7  # an argument the region learns inside
+    (rec,) = _spans(tel.journal)
+    kind, rname, _cat, _tid, t0, t1, rargs, _seq = rec
+    assert rname == name and t1 >= t0
+    assert rargs == {"tick": 3, "late": 7}
+    counts = {n: h.count for n, h in tel.registry.histograms.items()}
+    for h, n in counts.items():
+        assert n == (1 if h == hist else 0), h
+    if hist is not None:
+        assert tel.registry.histograms[hist].sum == pytest.approx(t1 - t0)
+
+
+def test_span_nesting_on_one_tid():
+    """The tick and admission spans share the host-scheduling track and
+    export as properly nested B/E pairs."""
+    tel = Telemetry()
+    with tel.span("engine_step", tick=1):
+        with tel.span("admit") as a:
+            a["admitted"] = 0
+        with tel.span("decode_tick", n_active=1):
+            pass
+    recs = {r[1]: r for r in _spans(tel.journal)}
+    assert recs["engine_step"][3] == recs["admit"][3] == TID_HOST
+    assert recs["decode_tick"][3] == TID_DEVICE
+    outer, inner = recs["engine_step"], recs["admit"]
+    assert outer[4] <= inner[4] <= inner[5] <= outer[5]
+    host = [(e["ph"], e["name"])
+            for e in tel.journal.to_chrome_trace()["traceEvents"]
+            if e["ph"] in "BE" and e["tid"] == TID_HOST]
+    assert host == [("B", "engine_step"), ("B", "admit"),
+                    ("E", "admit"), ("E", "engine_step")]
+
+
+def test_span_leaves_no_record_when_the_region_raises():
+    tel = Telemetry()
+    with pytest.raises(RuntimeError):
+        with tel.span("prefill_launch", tokens=1):
+            raise RuntimeError("launch failed")
+    assert len(tel.journal) == 0 and tel.h_prefill.count == 0
+    with tel.span("prefill_launch", tokens=1):  # the next span still works
+        pass
+    assert len(tel.journal) == 1
+
+
+def _chunked_engine(api, params, **kw):
+    return PagedEngine(api, params, n_slots=4, max_len=MAX_LEN, page_size=PS,
+                       chunked_prefill=True, prefill_chunk=PS,
+                       pipeline_depth=2, **kw)
+
+
+def test_engine_span_args_count_launched_rows(api_params):
+    """Chunked, depth 2: every decode launch packs all n_slots rows, its
+    ``tick`` matches one ``decode_sync``, and each chunk launch reports
+    its pow2 batch bucket, token bucket and whether it blocked."""
+    api, params = api_params
+    eng = _chunked_engine(api, params)
+    _run(eng, _prompts((5, 19, 12)), 5)
+    j = eng.telemetry.journal
+    ticks = eng.stats["decode_ticks"]
+    dec = [r[6] for r in _spans(j, "decode_tick")]
+    assert len(dec) == ticks > 0
+    assert sum(a["rows_launched"] for a in dec) == eng.n_slots * ticks
+    assert all(1 <= a["n_active"] <= a["rows_launched"] for a in dec)
+    sync_ticks = [r[6]["tick"] for r in _spans(j, "decode_sync")]
+    assert sorted(sync_ticks) == sorted(a["tick"] for a in dec)
+    assert len(set(sync_ticks)) == len(sync_ticks)
+
+    pre = [r[6] for r in _spans(j, "prefill_launch")]
+    assert len(pre) == eng.stats["prefill_launches"]
+    assert sum(a["tokens"] for a in pre) == eng.stats["prefill_tokens"]
+    for a in pre:
+        rows, bucket = a["rows_launched"], a["chunk_bucket"]
+        assert rows & (rows - 1) == 0 and a["slots"] <= rows
+        assert a["tokens"] <= rows * bucket and bucket <= PS
+    # a prompt finishes on 3 launches at least (one per request)
+    assert sum(a["synced"] for a in pre) >= 3
+
+    steps = [r[6]["tick"] for r in _spans(j, "engine_step")]
+    assert steps == list(range(1, len(steps) + 1))
+    assert len(_spans(j, "admit")) == len(steps)
+    assert sum(r[6]["admitted"] for r in _spans(j, "admit")) == 3
+    # each launch and sync nests inside the engine_step of its tick
+    step_span = {r[6]["tick"]: r for r in _spans(j, "engine_step")}
+    for r in _spans(j, "decode_tick") + _spans(j, "prefill_launch"):
+        outer = step_span[r[6]["tick"]]
+        assert outer[4] <= r[4] <= r[5] <= outer[5]
+
+
+def test_engine_spans_reach_the_profiler(api_params, tmp_path):
+    """At the default level the engine's spans are TraceAnnotations: a
+    profile taken around a few steps holds them on the host plane."""
+    from jax.profiler import ProfileData
+
+    api, params = api_params
+    eng = _chunked_engine(api, params)
+    _run(eng, _prompts((9,)), 2)  # warm the programs outside the trace
+    for i, p in enumerate(_prompts((13, 6))):
+        eng.submit(Request(rid=10 + i, prompt=p, max_new=4))
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(4):
+            eng.step()
+    eng.run_to_completion()
+    (path,) = list(tmp_path.rglob("*.xplane.pb"))
+    names = {
+        e.name
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+    for span in ("engine_step", "admit", "prefill_launch", "decode_tick",
+                 "decode_sync"):
+        assert span in names, span
+
+
+def test_step_programs_carry_their_names(api_params):
+    """The lowered decode and chunk steps are modules named after their
+    jit keys, so a device trace tells the two programs apart."""
+    api, params = api_params
+    eng = _chunked_engine(api, params)
+    low = eng.lower_steps()
+    assert low["decode"].as_text().startswith("module @jit_paged_decode_fused")
+    assert low["chunk"].as_text().startswith("module @jit_chunk_step")
+
+
+def test_trace_checker_requires_nested_spans(api_params, tmp_path):
+    """tools/check_telemetry.py passes the engine's export (spans nested
+    per tid) and fails a trace whose spans cross."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).parents[1] / "tools" / "check_telemetry.py"
+    spec = importlib.util.spec_from_file_location("check_telemetry", path)
+    check = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(check)
+
+    api, params = api_params
+    eng = _chunked_engine(api, params)
+    _run(eng, _prompts((11,)), 3)
+    good = tmp_path / "good.json"
+    eng.telemetry.dump_trace(str(good))
+    check.check_trace(str(good))
+
+    j = TraceJournal()
+    j.span("outer", 1.0, 3.0, tid=TID_HOST)
+    j.span("crossing", 2.0, 4.0, tid=TID_HOST)
+    bad = tmp_path / "bad.json"
+    j.dump(str(bad))
+    with pytest.raises(SystemExit):
+        check.check_trace(str(bad))

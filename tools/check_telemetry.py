@@ -6,7 +6,8 @@
 Checks the --metrics-json dump (schema version, required counters /
 gauges / histograms with the pinned bucket edges, timeline sanity) and
 the --trace-out Chrome trace (loadable, monotonic timestamps, every
-duration Begin paired with an End, thread-name metadata).  Exits
+duration Begin closed by the End of the same span with spans nested
+per thread, thread-name metadata).  Exits
 nonzero with a message on the first violation so CI fails loudly.
 
 Only stdlib — runnable on artifacts downloaded from a CI run without
@@ -142,7 +143,7 @@ def check_trace(path: str) -> None:
         fail(f"{path}: thread-name metadata missing ({meta_threads})")
     real = [e for e in evs if e["ph"] != "M"]
     last_ts = -1.0
-    depth: dict = {}
+    open_spans: dict = {}  # tid -> stack of open span names
     for e in real:
         for key in ("name", "ph", "ts", "pid", "tid"):
             if key not in e:
@@ -150,16 +151,20 @@ def check_trace(path: str) -> None:
         if e["ts"] < last_ts:
             fail(f"{path}: timestamps not monotonic at {e}")
         last_ts = e["ts"]
+        stack = open_spans.setdefault(e["tid"], [])
         if e["ph"] == "B":
-            depth[e["tid"]] = depth.get(e["tid"], 0) + 1
+            stack.append(e["name"])
         elif e["ph"] == "E":
-            depth[e["tid"]] = depth.get(e["tid"], 0) - 1
-            if depth[e["tid"]] < 0:
+            if not stack:
                 fail(f"{path}: End without Begin on tid {e['tid']}")
+            if stack.pop() != e["name"]:
+                fail(f"{path}: span {e['name']!r} ends inside another "
+                     f"on tid {e['tid']} (spans must nest)")
         elif e["ph"] != "i":
             fail(f"{path}: unexpected phase {e['ph']!r}")
-    if any(d != 0 for d in depth.values()):
-        fail(f"{path}: unbalanced spans at end of trace: {depth}")
+    if any(open_spans.values()):
+        fail(f"{path}: unbalanced spans at end of trace: "
+             f"{ {t: len(s) for t, s in open_spans.items()} }")
     spans = sum(1 for e in real if e["ph"] == "B")
     print(f"check_telemetry: {path} OK ({spans} spans, "
           f"{sum(1 for e in real if e['ph'] == 'i')} instants, "
