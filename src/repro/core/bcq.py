@@ -140,11 +140,16 @@ def pad_to_multiple(x: jax.Array, mult: int, axis: int = -1):
 
 
 def pack_nibbles(x: jax.Array) -> jax.Array:
-    """Pack 4-bit values (last axis, even length) two per uint8."""
+    """Pack 4-bit values (last axis, even length) two per uint8.
+
+    Even positions go to the low nibble, odd to the high one.  The pairs
+    come from a reshape to (..., n, 2), which lowers to slices: the same
+    bytes as strided ``x[..., 0::2]`` / ``x[..., 1::2]``, which lower to
+    gathers.
+    """
     x = x.astype(jnp.uint8)
-    lo = x[..., 0::2]
-    hi = x[..., 1::2]
-    return (hi << 4) | lo
+    pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    return (pairs[..., 1] << 4) | pairs[..., 0]
 
 
 def unpack_nibbles(p: jax.Array) -> jax.Array:
@@ -154,13 +159,49 @@ def unpack_nibbles(p: jax.Array) -> jax.Array:
 
 
 def nearest_level_idx(y: jax.Array, levels_sorted: jax.Array) -> jax.Array:
-    """Index of the nearest entry in a sorted 1-D level set, for each scalar.
+    """Index (int32) of the nearest entry of a sorted level set, per scalar.
 
-    side='right' ⇒ exact midpoints round to the upper level, matching the
-    Pallas kernel's ``(y >= thr)`` compares bit-for-bit.
+    ``levels_sorted`` holds the levels along its last axis; its leading
+    axes, if any, broadcast against ``y`` (one level set per block).
+    Counts the midpoints ``y`` reaches, ``Σ_t (y >= thr_t)`` over the
+    2^B−1 thresholds, unrolled into elementwise compares as in the Pallas
+    kernels' ``encode_tile``.  For finite ``y`` this is
+    ``searchsorted(thr, y, side="right")`` by definition (exact midpoints
+    round to the upper level), without its per-value binary-search loop
+    of gathers.
     """
-    thr = 0.5 * (levels_sorted[1:] + levels_sorted[:-1])
-    return jnp.searchsorted(thr, y, side="right")
+    idx = jnp.zeros(y.shape, jnp.int32)
+    for t in range(levels_sorted.shape[-1] - 1):
+        thr = 0.5 * (levels_sorted[..., t + 1] + levels_sorted[..., t])
+        idx += (y >= thr).astype(jnp.int32)
+    return idx
+
+
+def level_values(idx: jax.Array, levels: jax.Array) -> jax.Array:
+    """``levels[idx]`` for a 1-D level set, as a chain of selects.
+
+    One compare and select per level instead of a per-value gather; each
+    output is exactly one entry of ``levels``.
+    """
+    q = jnp.broadcast_to(levels[0], idx.shape)
+    for t in range(1, levels.shape[-1]):
+        q = jnp.where(idx == t, levels[t], q)
+    return q
+
+
+def block_sse(d: jax.Array) -> jax.Array:
+    """Σ d² over the last (block) axis, added left to right.
+
+    The order is fixed in the program, not left to XLA's reduce emitter,
+    which sums differently in different fusions: two codebooks that leave
+    a block the same residual get the same error bit for bit, so an exact
+    tie goes to the first one wherever the encode is inlined.
+    """
+    sq = d * d
+    err = sq[..., 0]
+    for j in range(1, sq.shape[-1]):
+        err = err + sq[..., j]
+    return err
 
 
 # -------------------------------------------------------------- encode path
@@ -184,25 +225,25 @@ def _select_and_index(blocks: jax.Array, codebooks: jax.Array):
     blocks: (..., L_b) normalized values; codebooks: (N_c, 2^B) sorted.
     Returns (sel int32 (...,), idx int32 (..., L_b)).
 
-    A running minimum over the codebooks (the first one wins ties, as
-    ``argmin`` would) keeps one codebook's candidates live at a time
-    instead of all N_c of them.
+    Each codebook's block error comes from :func:`nearest_level_idx`'s
+    threshold compares, :func:`level_values`' selects and
+    :func:`block_sse`'s fixed-order sum; one ``argmin`` over the N_c
+    errors picks the codebook (the first one wins ties).  ``idx`` is then
+    taken from the chosen codebook's own levels, so it agrees with ``sel``
+    however XLA fuses or duplicates the work.  The lowered encode holds
+    no gather and no loop.
     """
-
-    def one_cb(levels):
-        idx = nearest_level_idx(blocks, levels).astype(jnp.int32)
-        err = jnp.sum((blocks - levels[idx]) ** 2, axis=-1)
-        return err, idx
-
-    best, idx = one_cb(codebooks[0])
-    sel = jnp.zeros(best.shape, jnp.int32)
-    for c in range(1, codebooks.shape[0]):
-        err, cand = one_cb(codebooks[c])
-        better = err < best
-        best = jnp.where(better, err, best)
-        sel = jnp.where(better, c, sel)
-        idx = jnp.where(better[..., None], cand, idx)
-    return sel, idx
+    nc = codebooks.shape[0]
+    errs = []
+    for c in range(nc):
+        levels = codebooks[c]
+        q = level_values(nearest_level_idx(blocks, levels), levels)
+        errs.append(block_sse(blocks - q))
+    sel = jnp.argmin(jnp.stack(errs), axis=0).astype(jnp.int32)
+    chosen = jnp.broadcast_to(codebooks[0], sel.shape + codebooks.shape[1:])
+    for c in range(1, nc):
+        chosen = jnp.where((sel == c)[..., None], codebooks[c], chosen)
+    return sel, nearest_level_idx(blocks, chosen[..., None, :])
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -330,8 +371,7 @@ def _assign_mse(blocks: jax.Array, codebooks: jax.Array):
     def one_cb(levels):
         levels = jnp.sort(levels)
         idx = nearest_level_idx(blocks, levels)
-        q = levels[idx]
-        return jnp.sum((blocks - q) ** 2, axis=-1)
+        return block_sse(blocks - level_values(idx, levels))
 
     errs = jax.vmap(one_cb)(codebooks)  # (N_c, N_b)
     assign = jnp.argmin(errs, axis=0)

@@ -144,6 +144,10 @@ def encode_tile(xt: jax.Array, cb, s_x: jax.Array, cfg: BCQConfig):
     compare+select+FMA on the VPU — no gather.  K runs along sublanes, so
     the per-array and per-block reductions are sublane reductions.
     ``cb[i, t]`` must yield scalars (an SMEM ref or a concrete array).
+    Its XLA twin is ``core/bcq.encode``, which writes the bcq4 K/V pages
+    with the same compares and selects; it sums each block error left to
+    right, this reduction in its own order, so a near-tie between two
+    codebooks may go either way.
 
     Returns, K-major: idx (TK, TM) i32, sel (TK/L_b, TM) i32,
     ratio (TK/L_A, TM) f32 and the chosen codewords ``cb[sel, idx]``
