@@ -9,9 +9,12 @@ fused_linear=True, paged_kernel=True, cache_kind="bcq4")`` behind
 checks what comes out:
 
 1. the backend switches resolve to native Pallas (no interpret mode);
-2. the fused W4A4 linear (K=768 and K=3072) and the page-gather attention
-   (decode and chunked prefill over bcq4 pages) agree with their ``ref.py``
-   oracles within the stated bound, and a permuted codebook fails it;
+2. the fused W4A4 linear (K=768 and K=3072; and StarCoder2-3B's
+   K=12288 → 3072 at M = 128, 256 and 512, where K is split into blocks)
+   and the page-gather attention (decode and chunked prefill over bcq4
+   pages, gpt3's 12 heads and StarCoder2's 24 query heads over 2 KV heads
+   of 128 at 1 and 8 rows) agree with their ``ref.py`` oracles within the
+   stated bound, and a permuted codebook fails it;
 3. 8 requests (prompts of 64–384 tokens, 32 new tokens each, two sharing a
    128-token prefix) finish without error, the page audit is clean, and a
    second identical run retraces nothing;
@@ -64,6 +67,7 @@ import numpy as np
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 ARCH = "gpt3_126m"
+WIDE_ARCH = "starcoder2_3b"  # K = 12288 linears, 12 query heads a KV head
 SEED = 0
 N_REQ = 8
 NEW_TOKENS = 32
@@ -111,7 +115,7 @@ def check_linear(cb, cfg, k: int, n: int, m: int = 128) -> dict:
     got = ops.w4a4_linear_fused(x, pw, cb, cfg, s_x=s_x)
     perm = jax.random.permutation(jax.random.PRNGKey(SEED + 1), cb.size)
     bad = ops.w4a4_linear_fused(x, pw, cb.reshape(-1)[perm].reshape(cb.shape), cfg, s_x=s_x)
-    return _verdict(f"linear K={k} N={n}", got, want, bound, bad)
+    return _verdict(f"linear M={m} K={k} N={n}", got, want, bound, bad)
 
 
 def _verdict(name, got, want, bound, permuted) -> dict:
@@ -158,17 +162,18 @@ def _attn_bound(q, pool, bt, cfg, cb):
     kf = ref._dequant_pool_ref(dict(pool, _cb=cb), "k", "bcq4", cfg)[bt]
     vf = ref._dequant_pool_ref(dict(pool, _cb=cb), "v", "bcq4", cfg)[bt]
     b, maxp, ps, hkv, d = kf.shape
-    kf = kf.reshape(b, maxp * ps, hkv, d)
-    vmax = jnp.max(jnp.abs(vf.reshape(b, -1, hkv, d)), axis=(1, 3))  # (B, Hkv)
-    # q (B, C, H, D); H == Hkv here (MHA)
+    rep = q.shape[2] // hkv  # q (B, C, H, D): each KV head serves rep query heads
+    kf = jnp.repeat(kf.reshape(b, maxp * ps, hkv, d), rep, axis=2)
+    vmax = jnp.repeat(jnp.max(jnp.abs(vf.reshape(b, -1, hkv, d)), axis=(1, 3)), rep, axis=1)  # (B, H)
     with _highest():
         dot = jnp.einsum("bchd,bthd->bcht", jnp.abs(q), jnp.abs(kf))
     delta = 2.0**-7 * d**-0.5 * jnp.max(dot, axis=-1)  # (B, C, H)
     return (2 * delta + 2.0**-7)[..., None] * vmax[:, None, :, None]
 
 
-def check_attention(cb, cfg, hkv: int, d: int) -> list[dict]:
-    """Paged decode + chunked prefill over bcq4 pages vs ``ref.py``."""
+def check_attention(cb, cfg, h: int, hkv: int, d: int, b: int = 8) -> list[dict]:
+    """Paged decode + chunked prefill over bcq4 pages vs ``ref.py``: ``b``
+    rows of ``h`` query heads over ``hkv`` KV heads of ``d``."""
     import jax
     import jax.numpy as jnp
 
@@ -176,7 +181,8 @@ def check_attention(cb, cfg, hkv: int, d: int) -> list[dict]:
     from repro.kernels.chunked_prefill import chunked_prefill
     from repro.kernels.paged_attention import paged_attention
 
-    n_pages, b, maxp = 64, 8, 8
+    n_pages, maxp = 64, 8
+    tag = f"H={h} Hkv={hkv} D={d} rows={b}"
     pool = _bcq4_pool(cb, cfg, n_pages, hkv, d)
     rng = np.random.default_rng(SEED)
     bt = jnp.asarray(rng.integers(1, n_pages, (b, maxp)), jnp.int32)
@@ -185,24 +191,38 @@ def check_attention(cb, cfg, hkv: int, d: int) -> list[dict]:
     out = []
 
     lengths = jnp.asarray(rng.integers(1, maxp * PAGE + 1, b), jnp.int32)
-    q = jax.random.normal(jax.random.PRNGKey(SEED + 3), (b, hkv, d))
+    q = jax.random.normal(jax.random.PRNGKey(SEED + 3), (b, h, d))
     with _highest():
         want = ref.paged_attention_ref(q, pool, bt, lengths, "bcq4", cfg, cb)
     bound = _attn_bound(q[:, None], pool, bt, cfg, cb)[:, 0]
     got = paged_attention(q, pool, bt, lengths, "bcq4", cfg, cb)
     bad = paged_attention(q, pool, bt, lengths, "bcq4", cfg, cb_bad)
-    out.append(_verdict("paged_attention bcq4", got, want, bound, bad))
+    out.append(_verdict(f"paged_attention bcq4 {tag}", got, want, bound, bad))
 
     c = CHUNK
     n_past = jnp.asarray(rng.integers(0, maxp * PAGE // PAGE - c // PAGE + 1, b) * PAGE, jnp.int32)
-    qc = jax.random.normal(jax.random.PRNGKey(SEED + 4), (b, c, hkv, d))
+    qc = jax.random.normal(jax.random.PRNGKey(SEED + 4), (b, c, h, d))
     with _highest():
         want = ref.chunked_prefill_ref(qc, pool, bt, n_past, "bcq4", cfg, cb)
     bound = _attn_bound(qc, pool, bt, cfg, cb)
     got = chunked_prefill(qc, pool, bt, n_past, "bcq4", cfg, cb)
     bad = chunked_prefill(qc, pool, bt, n_past, "bcq4", cfg, cb_bad)
-    out.append(_verdict("chunked_prefill bcq4", got, want, bound, bad))
+    out.append(_verdict(f"chunked_prefill bcq4 {tag}", got, want, bound, bad))
     return out
+
+
+def kernel_checks(cb, cfg, arch, wide) -> list[dict]:
+    """Every kernel check: the served arch's linears and attention, and the
+    wide arch's K = d_ff linear at one to four M tiles and its GQA
+    attention (rep = H/Hkv query heads a KV head) at 1 and 8 rows."""
+    checks = [
+        check_linear(cb, cfg, arch.d_model, 3 * arch.d_model),
+        check_linear(cb, cfg, arch.d_ff, arch.d_model),
+    ] + check_attention(cb, cfg, arch.n_heads, arch.n_kv_heads, arch.head_dim)
+    checks += [check_linear(cb, cfg, wide.d_ff, wide.d_model, m) for m in (128, 256, 512)]
+    for b in (1, 8):
+        checks += check_attention(cb, cfg, wide.n_heads, wide.n_kv_heads, wide.head_dim, b)
+    return checks
 
 
 # ------------------------------------------------------------ serving
@@ -322,10 +342,7 @@ def main() -> int:
 
     # 2. kernels vs oracles
     t0 = time.perf_counter()
-    checks = [
-        check_linear(cb, cfg, arch.d_model, 3 * arch.d_model),
-        check_linear(cb, cfg, arch.d_ff, arch.d_model),
-    ] + check_attention(cb, cfg, arch.n_kv_heads, arch.head_dim)
+    checks = kernel_checks(cb, cfg, arch, get_arch(WIDE_ARCH))
     for c in checks:
         log(f"kernel check {json.dumps(c)}")
         if not c["ok"]:

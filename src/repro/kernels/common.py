@@ -198,16 +198,22 @@ def _pad_lanes(v: jax.Array, mult: int = LANES) -> jax.Array:
     return jnp.concatenate([v, jnp.zeros(v.shape[:-1] + (pad,), v.dtype)], axis=-1)
 
 
-def expand_lanes(src: jax.Array, rep: int, chunk: int) -> jax.Array:
+def expand_lanes(src, rep: int, chunk: int) -> jax.Array:
     """Lane ``l`` of 128-lane output chunk ``chunk`` ← ``src[:, (chunk·128
     + l) // rep]``: per-array scales (rep = L_A), per-block selector bytes
     (rep = 2·L_b) or index bytes (rep = 2) spread over the scalars they
-    cover.  ``src`` (R, n) is lane-padded to 128 here; the gather reads
-    one 128-lane source chunk, so ``rep`` must divide 128."""
+    cover.  ``src`` (R, n) is an array, lane-padded to 128 here, or a
+    VMEM ref, of which only that source chunk is loaded (integers widened
+    to int32); the gather reads one 128-lane source chunk, so ``rep`` must
+    divide 128."""
     assert LANES % rep == 0, rep
     base = chunk * LANES // rep
     blk = base // LANES * LANES
-    s = _pad_lanes(src)[:, blk : blk + LANES]
+    if isinstance(jax.typeof(src), jax.ref.AbstractRef):
+        s = src[:, blk : min(src.shape[1], blk + LANES)]
+        s = _pad_lanes(s.astype(jnp.int32) if jnp.issubdtype(s.dtype, jnp.integer) else s)
+    else:
+        s = _pad_lanes(src)[:, blk : blk + LANES]
     lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return _lane_gather(s, (base - blk) + lane // rep)
 
@@ -244,19 +250,23 @@ def flat_codebook(cb: jax.Array) -> jax.Array:
     return _pad_lanes(cb.astype(jnp.float32).reshape(1, -1))
 
 
-def decode_rows(idx_b, sel_b, inv, cb_row, cfg: BCQConfig, width: int) -> jax.Array:
-    """Dequantize R packed LO-BCQ rows to f32 (R, width), 128 lanes at a time.
+def decode_rows(idx_b, sel_b, inv, cb_row, cfg: BCQConfig, width: int,
+                start: int = 0) -> jax.Array:
+    """Dequantize scalars ``start .. start+width`` of R packed LO-BCQ rows
+    to f32 (R, width), 128 lanes at a time.
 
-    idx_b (R, width/2) and sel_b (R, ≥width/2L_b) are the packed index /
-    selector bytes as int32 (low nibble first), inv (R, width/L_A) the f32
-    per-array dequant scales — a weight row tile of the fused linear and a
-    bcq4 page's (token, head) vectors alike.  Each byte is spread over its
+    idx_b (R, ≥(start+width)/2) and sel_b (R, ≥(start+width)/2L_b) are the
+    packed index / selector bytes (low nibble first), inv (R, ≥(start+width)
+    /L_A) the f32 per-array dequant scales — a weight row tile of the fused
+    linear and a bcq4 page's (token, head) vectors alike; arrays or VMEM
+    refs, read one 128-lane chunk at a time.  Each byte is spread over its
     scalars by ``expand_lanes`` and its nibble picked by lane parity (no
     stack-and-reshape), then the combined codeword is looked up."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (idx_b.shape[0], LANES), 1)
     lb = cfg.block_len
+    first, off = divmod(start, LANES)
     chunks = []
-    for c in range(-(-width // LANES)):
+    for c in range(first, -(-(start + width) // LANES)):
         ib = expand_lanes(idx_b, 2, c)
         idx = jnp.where(lane % 2 == 0, ib & 0xF, ib >> 4)
         sb = expand_lanes(sel_b, 2 * lb, c)
@@ -264,7 +274,7 @@ def decode_rows(idx_b, sel_b, inv, cb_row, cfg: BCQConfig, width: int) -> jax.Ar
         vals = codebook_lookup(sel * cfg.n_entries + idx, cb_row)
         chunks.append(vals * expand_lanes(inv, cfg.array_len, c))
     out = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=1)
-    return out[:, :width]
+    return out[:, off : off + width]
 
 
 # ===================================================================== #

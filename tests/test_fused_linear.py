@@ -17,7 +17,7 @@ import pytest
 
 from repro.core import bcq, ptq
 from repro.core.bcq import BCQConfig
-from repro.kernels import ops, ref
+from repro.kernels import bcq_linear, ops, ref
 from repro.kernels.bcq_linear import bcq_linear_pallas
 from repro.kernels.bcq_quantize import bcq_quantize_pallas
 
@@ -56,6 +56,39 @@ def test_fused_bitexact_with_two_launch(cfg, tiles):
     )
     o_two = _two_launch(x, pw, cb, cfg, tiles)
     np.testing.assert_array_equal(np.asarray(o_fused), np.asarray(o_two))
+
+
+@pytest.mark.parametrize("k_block", [256, 512])
+def test_fused_k_blocks_bitexact_with_whole_k(monkeypatch, k_block):
+    """K split over the grid (the out block carrying the f32 accumulator
+    from one K block to the next, weights decoded at every step) equals
+    the whole-K step and the two-launch path bit for bit, over two M
+    tiles.  The K blocks come from the kernel's own choice, with the VMEM
+    budget lowered to what a ``k_block`` step states."""
+    cfg = BCQConfig()
+    m, n, k = 256, 128, 1024
+    x = jax.random.normal(jax.random.PRNGKey(11), (m, k))
+    w = jax.random.t(jax.random.PRNGKey(12), 3.0, (n, k))
+    cb = _codebooks(cfg)
+    pw = ops.quantize(w, cb, cfg, impl="ref")
+    s_x = bcq.tensor_scale(x, cfg)
+
+    def fused():
+        bcq_linear_pallas.clear_cache()  # the K extent is chosen at trace time
+        return np.asarray(bcq_linear_pallas(
+            x, pw.idx_packed, pw.sel_packed, pw.inv_scale, cb, s_x, cfg,
+            tile_m=128, tile_n=128, tile_k=256,
+        ))
+
+    assert bcq_linear.fit_k_block(k, 128, 128, 256, cfg) == k
+    whole = fused()
+    monkeypatch.setattr(bcq_linear, "VMEM_BUDGET", bcq_linear.vmem_bytes(128, 128, k, k_block, 256, cfg))
+    assert bcq_linear.fit_k_block(k, 128, 128, 256, cfg) == k_block
+    try:
+        np.testing.assert_array_equal(fused(), whole)
+    finally:
+        bcq_linear_pallas.clear_cache()
+    np.testing.assert_array_equal(whole, np.asarray(_two_launch(x, pw, cb, cfg, (128, 128, 256))))
 
 
 @pytest.mark.parametrize("cfg", CFGS, ids=lambda c: c.tag())
