@@ -41,18 +41,21 @@ a chunk (a decode query at position ``len-1`` sees exactly the tokens a
 chunk query at ``qpos = kv_len - C + c`` does under the single mask
 ``tpos <= qpos``).  Three hot-path properties:
 
-1. **Live-page-only grid.**  The old kernels ran grid ``(B, MAXP)`` —
-   every table slot of every sequence, NULL padding included, each step
-   DMA-ing a page and masking it dead.  The core instead runs a FLAT grid
-   of ``B·MAXP`` steps over a scalar-prefetched *schedule*: per sequence
-   ``ceil(kv_len/ps)`` live steps (min 1, so every output row is written),
-   concatenated; steps past the live total replay the last live step's
-   block indices.  Pallas/Mosaic elides the DMA whenever consecutive grid
-   steps map a block to the same index, so dead steps move **zero** page
-   bytes and the HBM traffic is exactly the live pages — the
-   ``null_page_bytes_skipped`` column of BENCH_paged.json.  Schedule
-   arrays (``sid``/``pin``/``first``/``last``/``live``, one int32 per
-   step) ride in scalar memory via ``PrefetchScalarGridSpec``.
+1. **Live-page-only grid.**  The core runs a FLAT grid over a
+   scalar-prefetched *schedule*: per sequence ``clip(ceil(kv_len/ps), 1,
+   MAXP)`` steps (``page_counts``; min 1, so every output row is
+   written), concatenated.  The grid's length is the live total,
+   ``Σ page_counts``, a bound known only at run time (one dynamic grid
+   bound), so no step runs past the last live page.  A static grid of
+   ``B·MAXP`` steps, with the dead tail replaying the last live block
+   index, moved no bytes there either, but paid a pipeline step for
+   each: at a 128-row decode launch with ~2 rows live that was ~16 k
+   steps a layer for ~200 of work.  HBM traffic is exactly the live
+   pages (NULL table padding is never visited).  Schedule arrays
+   (``sid``/``pin``/``first``/``last``/``live``, one int32 per step of
+   the ``B·MAXP`` bound) ride in scalar memory via
+   ``PrefetchScalarGridSpec``; they are built by compares and sums over
+   rows (``page_schedule``), with no gather or loop.
 
 2. **Lane-gather dequant for bcq4 pages.**  The page's (head, token)
    vectors are decoded as rows by ``decode_rows``: index and selector
@@ -323,6 +326,14 @@ def dequant_page(kind, refs, cfg: BCQConfig, cb_ref, sx):
     return vals.reshape(hkv, ps, 2 * d2)
 
 
+def page_counts(kv_len, page_size: int, maxp: int):
+    """Grid steps of each row, ``clip(ceil(kv_len/ps), 1, MAXP)``: its
+    live pages, and at least one so its output block is written.  Takes
+    NumPy and jax arrays alike, so the engine counts on the host the grid
+    the kernel runs."""
+    return ((kv_len + page_size - 1) // page_size).clip(1, maxp)
+
+
 def page_schedule(kv_len: jax.Array, page_size: int, maxp: int):
     """Flat live-page schedule for the page-gather grid.
 
@@ -330,26 +341,26 @@ def page_schedule(kv_len: jax.Array, page_size: int, maxp: int):
     int32 arrays — for flat step t: ``sid`` the sequence it serves,
     ``pin`` the page index within that sequence, ``first``/``last``
     whether t opens/closes its sequence's online softmax, ``live``
-    whether t does any work at all.  Sequence b gets
-    ``clip(ceil(kv_len/ps), 1, MAXP)`` consecutive steps (min 1 so its
-    output block is always written); steps beyond the live total replay
-    the last live step's indices, so every BlockSpec index map repeats
-    and the page DMAs for dead steps are elided."""
+    whether t does any work at all — and the live total, the grid's
+    length.  Sequence b gets ``page_counts`` consecutive steps; steps
+    beyond the live total (never run) replay the last live step's
+    indices.  Built by compares: ``sid`` counts the rows that end at or
+    before t and ``starts[sid]`` sums their counts, so nothing lowers to
+    a loop of gathers."""
     b = kv_len.shape[0]
-    g = b * maxp
-    counts = jnp.clip((kv_len + page_size - 1) // page_size, 1, maxp).astype(jnp.int32)
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts).astype(jnp.int32)]
-    )
-    total = starts[b]
-    t = jnp.arange(g, dtype=jnp.int32)
-    t_eff = jnp.minimum(t, total - 1)
-    sid = jnp.searchsorted(starts[1:], t_eff, side="right").astype(jnp.int32)
-    pin = t_eff - starts[sid]
-    live = (t < total).astype(jnp.int32)
-    first = ((pin == 0) & (t < total)).astype(jnp.int32)
-    last = ((pin == counts[sid] - 1) & (t < total)).astype(jnp.int32)
-    return sid, pin, first, last, live
+    counts = page_counts(kv_len.astype(jnp.int32), page_size, maxp).astype(jnp.int32)
+    ends = jnp.cumsum(counts, dtype=jnp.int32)  # starts[1:]
+    total = ends[b - 1]
+    t = jnp.arange(b * maxp, dtype=jnp.int32)
+    t_eff = jnp.minimum(t, total - 1)[:, None]
+    before = ends[None, :] <= t_eff  # (B·MAXP, B): row wholly before step t
+    sid = jnp.sum(before, axis=1, dtype=jnp.int32)
+    pin = t_eff[:, 0] - jnp.sum(jnp.where(before, counts[None, :], 0), axis=1, dtype=jnp.int32)
+    live = t < total
+    first = (pin == 0) & live
+    last = jnp.any(ends[None, :] == t_eff + 1, axis=1) & live
+    return (sid, pin, first.astype(jnp.int32), last.astype(jnp.int32),
+            live.astype(jnp.int32), total)
 
 
 def _page_gather_kernel(
@@ -432,9 +443,10 @@ def page_gather_attention(
     (B, C, H, D) f32.  See the module docstring for the grid schedule.
 
     The page BlockSpecs are double-buffered (the Pallas TPU pipeline's
-    default of two buffers per input): the pipeline issues step t+1's K/V page DMAs — their block indices come
-    from the scalar-prefetched schedule — before step t computes, and
-    skips the copy when the index repeats (dead tail steps)."""
+    default of two buffers per input): the pipeline issues step t+1's
+    K/V page DMAs — their block indices come from the scalar-prefetched
+    schedule — before step t computes, and skips the copy when the index
+    repeats."""
     b, nq, h, d = q.shape
     maxp = block_tables.shape[1]
     if kind == "bcq4" and d % cfg.array_len:
@@ -449,7 +461,7 @@ def page_gather_attention(
     qg = q.reshape(b, nq, hkv, rep, d).transpose(0, 2, 3, 1, 4)
     qg = qg.reshape(b, hkv, rep * nq, d)
 
-    sid, pin, first, last, live = page_schedule(kv_len, ps, maxp)
+    sid, pin, first, last, live, total = page_schedule(kv_len, ps, maxp)
 
     def page_spec(leaf):
         blk = (1,) + leaf.shape[1:]
@@ -489,7 +501,7 @@ def page_gather_attention(
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
-        grid=(b * maxp,),
+        grid=(total,),  # dynamic: only the live steps run
         in_specs=in_specs,
         out_specs=row_spec(qg.shape),
         scratch_shapes=scratch_shapes,

@@ -161,6 +161,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.common import page_counts
 from repro.serving import pages as pages_lib
 from repro.serving.audit import AuditReport, audit_engine
 from repro.serving.generate import (
@@ -1773,12 +1774,17 @@ class PagedEngine:
         synced = self.profile_sync or any(
             plans[i][0] + plans[i][1] == len(self.slots[i].pending) for i in batch
         )
+        # the page-gather grid a layer runs: row r attends over
+        # n_past + c_bucket tokens, a padding row (n_past 0) over c_bucket
+        kv = np.full((bb,), c_bucket, np.int64)
+        kv[: len(batch)] += [plans[i][0] for i in batch]
         if self.faults is not None:
             self.faults.delay_launch(self._tick, key=2)
         with self.telemetry.span(
             "prefill_launch", slots=len(batch),
             tokens=int(sum(plans[i][1] for i in batch)), tick=self._tick,
             rows_launched=bb, chunk_bucket=c_bucket, synced=bool(synced),
+            grid_steps=int(page_counts(kv, self.ps, w).sum()),
         ):
             # one packed int32 staging array → ONE host→device transfer
             # per chunk tick (tokens | n_past | scatter ids | chunk_len |
@@ -2042,23 +2048,35 @@ class PagedEngine:
             active = [i for i in active if self.slots[i].req is not None
                       and self.slots[i].mode == "decode"]
             if active:
-                self._decode_tick(active, quiet=(served == 0 and admitted == 0))
+                self._decode_tick(
+                    active, quiet=(served == 0 and admitted == 0),
+                    span_args={"grid_steps": self._decode_grid_steps(active)},
+                )
             else:
                 self.drain()
             if self.audit_every and self._tick % self.audit_every == 0:
                 self.audit()
         return served + len(active)
 
-    def _decode_tick(self, active: list, *args, **kwargs) -> None:
+    def _decode_grid_steps(self, active: list) -> int:
+        """The page-gather grid a layer of the decode launch over
+        ``active`` runs: each live row attends over ``pos + 1`` tokens,
+        and each idle row (length 0) takes one step."""
+        kv = np.asarray([self.slots[i].pos + 1 for i in active])
+        steps = page_counts(kv, self.ps, self.tables.shape[1]).sum()
+        return self.n_slots - len(active) + int(steps)
+
+    def _decode_tick(self, active: list, *args, span_args=None, **kwargs) -> None:
         """``_launch_decode(active, *args, **kwargs)`` inside the
-        ``decode_tick`` span, then sync while the pipeline is full.  At
-        depth 1 the span also covers the merged sync (legacy
-        attribution); deeper, each sync is its own ``decode_sync``."""
+        ``decode_tick`` span (with ``span_args`` besides its own), then
+        sync while the pipeline is full.  At depth 1 the span also covers
+        the merged sync (legacy attribution); deeper, each sync is its
+        own ``decode_sync``."""
         if self.faults is not None:
             self.faults.delay_launch(self._tick, key=1)
         with self.telemetry.span(
             "decode_tick", n_active=len(active), tick=self._tick,
-            rows_launched=self.n_slots,
+            rows_launched=self.n_slots, **(span_args or {}),
         ):
             t0 = self._launch_decode(active, *args, **kwargs)
             if self.pipeline_depth == 1:

@@ -8,6 +8,7 @@ import pytest
 from repro.core.bcq import BCQConfig
 from repro.core.calibrate import default_universal_codebooks
 from repro.kernels import ref as kref
+from repro.kernels.common import page_gather_attention, page_schedule
 from repro.kernels.paged_attention import paged_attention
 from repro.models import layers
 
@@ -192,3 +193,75 @@ def test_model_paged_gather_matches_kernel():
     ref = jnp.einsum("bht,bthd->bhd", p, jnp.repeat(vf, 2, 2))
     got = paged_attention(q, pool, bt, lengths, "bcq4", CFG, CB, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+# ------------------------------------------------ the schedule and its grid
+def _schedule_oracle(kv_len, ps, maxp):
+    """The schedule by ``np.searchsorted``: flat step t serves the row
+    whose [start, end) holds min(t, total - 1)."""
+    counts = np.clip(-(-kv_len // ps), 1, maxp)
+    starts = np.concatenate([[0], np.cumsum(counts)])
+    total = starts[-1]
+    t = np.arange(len(kv_len) * maxp)
+    t_eff = np.minimum(t, total - 1)
+    sid = np.searchsorted(starts[1:], t_eff, side="right")
+    pin = t_eff - starts[sid]
+    live = t < total
+    return sid, pin, (pin == 0) & live, (pin == counts[sid] - 1) & live, live, total
+
+
+@pytest.mark.parametrize(
+    "kv_len",
+    [
+        [0] * 8,  # every row idle: one step each
+        [4 * PS] + [0] * 7,  # one row at MAXP, the rest idle
+        [3, 17, 0, 9, 24, 1, 0, 16],  # ragged
+        [9 * PS, 4 * PS + 1, 0, 100 * PS],  # past MAXP·ps: clipped
+        [13],  # a single row
+    ],
+    ids=["all-idle", "one-full-row", "ragged", "clipped", "one-row"],
+)
+def test_page_schedule_matches_searchsorted_oracle(kv_len):
+    maxp = 4
+    kv = np.asarray(kv_len, np.int32)
+    got = jax.jit(page_schedule, static_argnums=(1, 2))(jnp.asarray(kv), PS, maxp)
+    want = _schedule_oracle(kv.astype(np.int64), PS, maxp)
+    for name, g, w in zip(("sid", "pin", "first", "last", "live", "total"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w, np.int32), err_msg=name)
+
+
+@pytest.mark.parametrize("kind", ("bf16", "bcq4"))
+def test_decode_launch_of_mostly_idle_rows(kind):
+    """The serving decode shape: many rows idle (length 0, all-NULL
+    tables) beside a few multi-page rows.  The grid runs only the live
+    steps, one per idle row, yet every row's output is written and equals
+    the oracle."""
+    pool = _pool(kind)
+    b, maxp = 16, 4
+    rng = np.random.default_rng(3)
+    bt = np.zeros((b, maxp), np.int32)
+    lengths = np.zeros((b,), np.int32)
+    for r, n in ((2, 4 * PS), (9, 2 * PS + 3), (13, 5)):
+        lengths[r] = n
+        bt[r] = rng.integers(1, P, maxp)
+    q = jax.random.normal(jax.random.PRNGKey(13), (b, 4, D))
+    bt, lengths = jnp.asarray(bt), jnp.asarray(lengths)
+    ref = kref.paged_attention_ref(q, pool, bt, lengths, kind, CFG, CB)
+    got = paged_attention(q, pool, bt, lengths, kind, CFG, CB, interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+def test_page_gather_grid_is_bounded_by_the_live_total():
+    """The lowered ``pallas_call`` has one grid axis, bounded at run time
+    (one dynamic grid bound), not the static B·MAXP."""
+    pool = _pool("bcq4")
+    q = jnp.zeros((16, 1, 4, D), jnp.float32)
+    bt = jnp.zeros((16, 4), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda q, pool, bt, kv: page_gather_attention(q, pool, bt, kv, "bcq4", CFG, CB, True)
+    )(q, pool, bt, jnp.zeros((16,), jnp.int32))
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    gm = calls[0].params["grid_mapping"]
+    assert gm.num_dynamic_grid_bounds == 1 and len(gm.grid) == 1

@@ -424,6 +424,41 @@ def test_engine_span_args_count_launched_rows(api_params):
         assert outer[4] <= r[4] <= r[5] <= outer[5]
 
 
+def test_engine_span_grid_steps_match_the_launched_lengths(api_params):
+    """``grid_steps`` on ``decode_tick`` and ``prefill_launch`` is the
+    page-gather grid a layer of that launch runs, Σ clip(ceil(kv/ps), 1,
+    MAXP) over the launched rows: counted here from the packed arrays the
+    launches carry (decode: kv = length + 1; chunk: kv = n_past + bucket,
+    padding rows included)."""
+    from repro.kernels.common import page_counts
+
+    api, params = api_params
+    eng = _chunked_engine(api, params)
+    want = {"decode_tick": [], "prefill_launch": []}
+    decode, chunk = eng._decode, eng._chunk_step
+
+    def counted(packed, kv_col, extra):
+        p = np.asarray(packed)
+        return int(page_counts(p[:, kv_col] + extra, PS, eng.tables.shape[1]).sum())
+
+    def spy_decode(params_, pool, packed, chain):
+        want["decode_tick"].append(counted(packed, 2, 1))
+        return decode(params_, pool, packed, chain)
+
+    def spy_chunk(params_, packed, c, n_cp):
+        want["prefill_launch"].append(counted(packed, c, c))
+        return chunk(params_, packed, c, n_cp)
+
+    eng._decode, eng._chunk_step = spy_decode, spy_chunk
+    _run(eng, _prompts((5, 19, 12)), 5)
+    for name, steps in want.items():
+        got = [r[6]["grid_steps"] for r in _spans(eng.telemetry.journal, name)]
+        assert got == steps and len(steps) > 0, name
+    dec = [r[6] for r in _spans(eng.telemetry.journal, "decode_tick")]
+    # idle rows take one step each; live rows their pages
+    assert all(a["rows_launched"] < a["grid_steps"] for a in dec if a["n_active"] > 1)
+
+
 def test_engine_spans_reach_the_profiler(api_params, tmp_path):
     """At the default level the engine's spans are TraceAnnotations: a
     profile taken around a few steps holds them on the host plane."""

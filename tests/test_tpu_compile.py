@@ -151,3 +151,25 @@ def test_page_gather_gqa_compiles_for_v5e(one_chip, chunk):
         s((CFG.n_codebooks, CFG.n_entries), jnp.float32),
     )
     assert "tpu_custom_call" in txt and "page_gather_attention" in txt
+
+
+@pytest.mark.parametrize(
+    "arch,maxp",
+    [(ARCH, 2048 // PS), (SC2, 4096 // PS)],
+    ids=["gpt3_126m-128x128", "starcoder2_3b-128x256"],
+)
+def test_page_gather_decode_launch_compiles_for_v5e(one_chip, arch, maxp):
+    """The served decode launch: 128 rows over tables of MAXP pages (gpt3:
+    12 heads of 64, 128 pages; StarCoder2: GQA 24/2 of 128, 256 pages),
+    its grid bounded at run time by the live-step total."""
+    def attend(q, pool, bt, kv_len, cb):
+        return page_gather_attention(q, pool, bt, kv_len, "bcq4", CFG, cb, interpret=False)
+
+    s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
+    lowered = jax.jit(attend).lower(
+        s((128, 1, arch.n_heads, arch.head_dim), jnp.bfloat16),
+        _pool_specs(one_chip, "bcq4", arch), s((128, maxp), jnp.int32), s((128,), jnp.int32),
+        s((CFG.n_codebooks, CFG.n_entries), jnp.float32),
+    )
+    txt = lowered.compile().as_text()
+    assert "tpu_custom_call" in txt and "page_gather_attention" in txt
