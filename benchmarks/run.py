@@ -17,9 +17,6 @@ def main() -> None:
 
     from benchmarks import (
         fig4_convergence,
-        kernel_bench,
-        paged_bench,
-        roofline_report,
         table1_bitwidth,
         table2_ppl,
         table5_sub4bit,
@@ -35,10 +32,7 @@ def main() -> None:
         "table8": table8_ablation,
         "table9": table9_universal,
         "table10": table10_codeword,
-        "kernels": kernel_bench,
-        "paged": paged_bench,
         "table2": table2_ppl,
-        "roofline": roofline_report,
     }
     if args.only:
         mods = {k: v for k, v in mods.items() if k in args.only.split(",")}

@@ -23,8 +23,9 @@ checks what comes out:
    Pallas kernels (``tpu_custom_call``);
 5. the engine's first-token logits (``Request.keep_prompt_logits``) agree
    with the same requests served through the jnp route
-   (``paged_kernel=False, fused_linear=False``), and the kernel route fed
-   a permuted codebook does not.
+   (``paged_kernel=False, fused_linear=False``: every linear through the
+   fused linear's jnp oracle, attention over dequantized pages), and the
+   kernel route fed a permuted codebook does not.
 
 Run from the repo root of a machine with a TPU:  ``python3 chip_smoke.py``.
 It refuses to run where JAX finds no TPU.  The last stdout line is one JSON
@@ -43,17 +44,17 @@ Tolerances (why each is what it is):
   ``δ = 2^-7 · scale · max_t Σ_d |q_d|·|k_td|``.  A permuted codebook
   moves outputs by O(Σ|â||ŵ|) and O(max|v|) and fails both bounds — the
   script checks that too.
-* Engine logits.  The jnp route rounds both dequantized operands to bf16
-  before its einsum and re-quantizes every activation to 4 bits, so a
-  one-ulp difference can flip a 4-bit code and grow through 12 layers of a
-  random-weight model whose logits are noise-sized.  With f32 compute the
-  two routes agree to ~4e-5; with the served bf16 compute they differ by a
-  relative L2 of 0.13–0.21 (smoke-size CPU run) and 0.143–0.160 (this
-  script on a v5e), while the kernel route fed a permuted codebook gives
-  1.26–1.51 (smoke-size CPU run) and 1.375–1.441 (this script on a v5e).
-  The first-token logits must agree to ``‖Δ‖/‖ref‖ ≤ LOGIT_RTOL = 0.5``
-  per request, and the permuted codebook must exceed it on every request:
-  3× the largest sound reading, under half the smallest wrong one.
+* Engine logits.  Both routes re-quantize every activation to 4 bits
+  from bf16 activations, and their dots and attention round differently,
+  so a one-ulp difference can flip a 4-bit code and grow through 12
+  layers of a random-weight model whose logits are noise-sized.  On a
+  v5e the two routes differ by a relative L2 of 0.133–0.155 (this script,
+  the jnp route's linears on the fused linear's oracle; 0.143–0.160 when
+  they ran decode + bf16 einsum), while the kernel route fed a permuted
+  codebook gives 1.363–1.459 (1.375–1.441 then).  The first-token logits
+  must agree to ``‖Δ‖/‖ref‖ ≤ LOGIT_RTOL = 0.5`` per request, and the
+  permuted codebook must exceed it on every request: 3× the largest
+  sound reading, under half the smallest wrong one.
 """
 from __future__ import annotations
 

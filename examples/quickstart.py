@@ -5,7 +5,8 @@
 2. Show the non-increasing MSE trajectory (§A.2 invariant).
 3. Encode → packed 4.5-bit buffers → decode; compare NMSE against the
    MX4 / MXFP4 / VSQ baselines at matched bitwidth (Fig. 4/9 analogue).
-4. Run the W4A4 Pallas decode-GEMM (interpret mode on CPU) against the
+4. Run the fused W4A4 linear (one Pallas kernel: activation encode in
+   VMEM, weight decode, GEMM; interpret mode on CPU) against the
    fake-quant reference.
 
   PYTHONPATH=src python examples/quickstart.py
@@ -40,14 +41,16 @@ def main():
     for name, (fn, bits) in baselines.BASELINES.items():
         print(f"NMSE  {name:14s}({bits}b): {float(bcq.quantization_nmse(x, fn(x))):.5f}")
 
-    # packed W4A4 GEMM through the Pallas kernel (interpret on CPU)
+    # W4A4 linear through the fused Pallas kernel (interpret on CPU):
+    # raw activations in, weights pre-packed
     w = jax.random.normal(jax.random.fold_in(key, 2), (256, 1024))
-    pa = ops.quantize(x[:64], cb, cfg, impl="pallas", tile_m=64, tile_k=512)
-    pw = ops.quantize(w, cb, cfg, impl="pallas", tile_m=64, tile_k=512)
-    out = ops.matmul(pa, pw, cb, cfg, impl="pallas", tile_m=64, tile_n=64, tile_k=512)
+    pw = ops.quantize(w, cb, cfg)
+    out = ops.w4a4_linear_fused(
+        x[:64], pw, cb, cfg, impl="pallas", tile_m=64, tile_n=64, tile_k=512
+    )
     ref = bcq.fake_quant(x[:64], cb, cfg) @ bcq.fake_quant(w, cb, cfg).T
     err = float(jnp.max(jnp.abs(out - ref)))
-    print(f"\nPallas W4A4 GEMM vs fake-quant reference: max |Δ| = {err:.2e}")
+    print(f"\nfused W4A4 linear vs fake-quant reference: max |Δ| = {err:.2e}")
     storage = (pw.idx_packed.size + pw.sel_packed.size + pw.inv_scale.size) / w.size
     print(f"packed weight storage: {storage*8:.2f} bits/scalar (incl. f32 staging scales)")
 

@@ -6,10 +6,10 @@ DESIGN
 ``out = Â · Ŵᵀ`` where Â = LO-BCQ(x) is encoded **inside the kernel** and Ŵ
 arrives pre-packed (4-bit indices + selector/scale metadata, the
 ``ops.PackedOperand`` layout — the index bytes, the bulk of the weight
-stream, are not re-laid before the launch).  The
-two-launch path (`bcq_quantize_pallas` + `bcq_matmul_pallas`) round-trips
-packed activations through HBM and re-decodes every weight tile O(M/TM)
-times with an O(N_c·2^B) masked-sum mux; this kernel removes both costs:
+stream, are not re-laid before the launch).  Packed
+activations never round-trip through HBM, and each weight tile is
+decoded by lane gathers, not an O(N_c·2^B) masked-sum mux, once per N
+tile where K fits whole:
 
 1. **Grid (N/TN, M/TM[, K/KB]): K in blocks of KB, whole K where it fits.**
    KB is the largest multiple of ``tile_k`` dividing K whose step fits
@@ -17,8 +17,8 @@ times with an O(N_c·2^B) masked-sum mux; this kernel removes both costs:
    width, 4096 of StarCoder2-3B's d_ff = 12288.  Inside a step K is
    walked by an in-kernel ``fori_loop`` of ``tile_k`` chunks, and the f32
    out block (resident across the K axis) carries the accumulator from
-   one K block to the next, so the accumulation order over K is the
-   two-launch path's whatever KB is.  The packed selector and scale rows
+   one K block to the next, so the accumulation order over K is one
+   ``tile_k`` tile after another whatever KB is.  The packed selector and scale rows
    (KB/(2·L_b) bytes, KB/L_A floats: not 128-aligned) reach a K-blocked
    launch re-laid as (K/KB, N, ·), so that each K block's metadata is a
    whole minor dim; a whole-K launch (grid (N/TN, M/TM)) takes them as
@@ -30,9 +30,9 @@ times with an O(N_c·2^B) masked-sum mux; this kernel removes both costs:
    fetches it from HBM once per M tile (for the serving-decode hot path
    — a single M tile — exactly once per linear, regardless of N/TN), and
    each loop step takes a (TK, TM) chunk at a sublane offset.  The chunk
-   is encoded with `common.encode_tile` — the *same* threshold-compare
-   routine the standalone quantize kernel runs, so the fused path is
-   bit-exact with the two-launch path by construction.  The encode
+   is encoded with `common.encode_tile`: the threshold-compare
+   encode of ``ref.quantize_ref``, K-major, whose chosen codewords
+   equal the oracle's up to near-ties between codebooks.  The encode
    already selects each scalar's codeword, so the activation needs no
    decode at all.  Packed activations never touch HBM: the only
    activation HBM stream is the raw read (plus the wrapper's transpose).
@@ -86,12 +86,12 @@ exactly once; with KB < K, and in multi-M-tile prefill, the slab re-streams
 per N tile like any GEMM operand (12288 → 3072 at M = 128: 24 × 6 MiB,
 against 21.7 MB of packed weights).
 
-Bit-exactness vs the two-launch path: identical encode (shared
-`encode_tile`), identical decoded values (a gather moves bits; the
-encode's chosen codeword is ``cb[sel, idx]`` exactly), identical dequant
-scales (same ``1/(ŝ_A·s_X)`` f32 arithmetic), and identical accumulation
-order over K — tested bitwise in tests/test_fused_linear.py (interpret
-mode).  On the chip the final dot runs at the MXU's default precision;
+Bit-exactness vs the oracle's pieces (``ref.quantize_ref``, ``ref.decode_ref``):
+the same codes (near-ties between codebooks aside), identical decoded values
+(a gather moves bits; the encode's chosen codeword is ``cb[sel, idx]``),
+the same dequant scales (``1/(ŝ_A·s_X)`` in f32), accumulated over K one
+``tile_k`` tile at a time — tested bitwise in tests/test_fused_linear.py
+(interpret mode).  On the chip the final dot runs at the MXU's default precision;
 chip_smoke.py states the tolerance against the f32 oracle.
 """
 from __future__ import annotations
@@ -205,7 +205,7 @@ def _fused_kernel(
         )[:, :tile_k]
         # scale AFTER the transpose: the dot's operand is then a product,
         # which XLA cannot fold into the dot's dimension numbers — so the
-        # interpreter accumulates exactly like the two-launch path
+        # interpreter accumulates each K tile as one plain dot
         a = q.T * inv  # (TM, TK)
         return acc + jax.lax.dot_general(
             a, w_cache[s], (((1,), (1,)), ((), ())),

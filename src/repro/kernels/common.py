@@ -1,14 +1,13 @@
 """Shared helpers for the LO-BCQ Pallas kernels.
 
-Single home for the pieces that used to be copy-pasted across
-``bcq_quantize.py`` / ``bcq_matmul.py`` (and mirrored in ``ref.py``):
+The pieces the fused W4A4 linear and the paged attention kernels share
+(their jnp oracles are in ``ref.py``):
 
-* nibble packing (``pack_u4`` / ``unpack_u4``),
 * the kernel-safe E4M3 round-to-nearest (``e4m3_snap``),
 * backend-aware ``interpret`` resolution (``resolve_interpret``),
 * the threshold-compare LO-BCQ encode of one VMEM tile (``encode_tile``),
-  used by both the standalone quantize kernel and the fused linear kernel —
-  sharing the code is what makes the two paths bit-exact by construction,
+  which the fused linear kernel runs on each activation tile (its
+  oracle is ``ref.quantize_ref``; ``core/bcq.encode`` is its XLA twin),
 * the packed-row decode ``decode_rows`` (weight row tiles of the fused
   linear and bcq4 pages alike), built on the codeword lookup
   ``codebook_lookup`` and the metadata expansion ``expand_lanes``:
@@ -120,22 +119,6 @@ def e4m3_snap(a: jax.Array) -> jax.Array:
     q = jnp.round(a * pow2(3 - e)) * pow2(e - 3)
     q = jnp.minimum(q, _E4M3_MAX)
     return jnp.maximum(q, _E4M3_MIN_SUB)
-
-
-def pack_u4(x: jax.Array) -> jax.Array:
-    """(T, 2n) uint values < 16 → (T, n) packed uint8, low nibble first."""
-    x = x.astype(jnp.uint8)
-    lo = x[:, 0::2]
-    hi = x[:, 1::2]
-    return (hi << 4) | lo
-
-
-def unpack_u4(p: jax.Array) -> jax.Array:
-    """(T, n) packed uint8 → (T, 2n) int32 nibbles, low nibble first."""
-    lo = (p & 0xF).astype(jnp.int32)
-    hi = (p >> 4).astype(jnp.int32)
-    t, n = p.shape
-    return jnp.stack([lo, hi], axis=-1).reshape(t, n * 2)
 
 
 def encode_tile(xt: jax.Array, cb, s_x: jax.Array, cfg: BCQConfig):

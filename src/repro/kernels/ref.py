@@ -77,7 +77,7 @@ def inv_scale(ratio: jax.Array, s_x: jax.Array) -> jax.Array:
     return 1.0 / (ratio * s_x)
 
 
-@partial(jax.jit, static_argnames=("cfg", "valid_k"))
+@partial(jax.jit, static_argnames=("cfg",))
 def fused_linear_ref(
     x: jax.Array,
     w_idx: jax.Array,
@@ -86,23 +86,15 @@ def fused_linear_ref(
     codebooks: jax.Array,
     cfg: BCQConfig,
     s_x: jax.Array,
-    valid_k: int | None = None,
 ) -> jax.Array:
     """Oracle for the fused W4A4 linear (kernels/bcq_linear.py).
 
-    Encode x (M, Kp) on the fly, decode both operands, contract over K —
-    the jnp composition of quantize_ref + matmul_ref, so it is bit-exact
-    with the two-launch path by construction.  ``valid_k`` (static) zeroes
-    the activation dequant scale for padded-K arrays, matching the padding
-    contract of ops.quantize."""
+    Encode x (M, K) on the fly, decode both operands, contract over K in
+    one dot: the jnp composition of quantize_ref + matmul_ref."""
     idx_p, sel_p, ratio = quantize_ref(x, codebooks, cfg, s_x)
-    a_inv = inv_scale(ratio, s_x)
-    if valid_k is not None:
-        ka = x.shape[1] // cfg.array_len
-        valid = (jnp.arange(ka) * cfg.array_len) < valid_k
-        a_inv = a_inv * valid[None, :]
     return matmul_ref(
-        idx_p, sel_p, a_inv, w_idx, w_sel, w_inv, codebooks, codebooks, cfg
+        idx_p, sel_p, inv_scale(ratio, s_x), w_idx, w_sel, w_inv,
+        codebooks, codebooks, cfg,
     )
 
 

@@ -32,8 +32,7 @@ so host scheduling overlaps device compute; 1 restores the synchronous
 loop — tokens are bit-identical at any depth).
 ``--packed`` also serves through the true-storage path: weights held as
 packed 4-bit buffers and every linear dispatched to the fused
-quantize→decode→GEMM kernel (kernels/bcq_linear.py; ``--unfused`` falls
-back to in-graph decode_packed_weight + einsum for comparison).
+quantize→decode→GEMM kernel (kernels/bcq_linear.py).
 ``--best-of N`` serves every prompt as an N-way SEQUENCE FORK through the
 paged engine: one prefill, then N sibling decode branches that share all
 prompt pages by refcount (zero copies, zero recompute) and copy-on-write
@@ -365,8 +364,6 @@ def main():
                          "bit-identical either way)")
     ap.add_argument("--packed", action="store_true",
                     help="also serve with packed 4-bit weights (fused kernel path)")
-    ap.add_argument("--unfused", action="store_true",
-                    help="with --packed: use decode_packed_weight + einsum instead")
     ap.add_argument("--best-of", type=int, default=1,
                     help="fork every prompt into N sampled siblings through "
                          "the paged engine (prompt pages shared by refcount, "
@@ -506,9 +503,7 @@ def main():
     if args.packed:
         # true-storage serving: packed 4-bit weight buffers end-to-end,
         # linears dispatched to the fused quantize→decode→GEMM kernel
-        rt_pk = dataclasses.replace(
-            rt_w4a4, quant_mode="packed", fused_linear=not args.unfused
-        )
+        rt_pk = dataclasses.replace(rt_w4a4, quant_mode="packed")
         api_pk = zoo.build(cfg, rt_pk)
         params_pk = ptq.pack_params(params, cb, bcq_cfg)
         params_pk["codebooks"] = cb
@@ -519,8 +514,8 @@ def main():
         agree_pk = float(jnp.mean((got_pk == ref).astype(jnp.float32)))
         print(
             f"packed : {toks/t_pk:8.1f} tok/s "
-            f"({'fused w4a4_linear kernel' if not args.unfused else 'decode+einsum'}, "
-            f"4-bit weight buffers) agreement vs bf16: {agree_pk*100:.1f}%"
+            f"(fused W4A4 linear, 4-bit weight buffers) "
+            f"agreement vs bf16: {agree_pk*100:.1f}%"
         )
 
     if args.paged and is_state:

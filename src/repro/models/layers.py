@@ -9,9 +9,9 @@ array living in the param tree), so a single model definition serves:
   quant_mode='fake'      W4A4 serving: acts quantized on the fly, weights
                          PTQ'd offline (paper §4.1 fn.3 emulation),
   quant_mode='fake_full' also quantizes weights in-graph,
-  quant_mode='packed'    weights stored as packed 4-bit buffers and decoded
-                         in-graph (true-storage serving path; on TPU the
-                         Pallas kernels of kernels/ implement the same math).
+  quant_mode='packed'    weights stored as packed 4-bit buffers, every
+                         linear one fused W4A4 launch (kernels/bcq_linear.py)
+                         or its jnp oracle (the served path).
 """
 from __future__ import annotations
 
@@ -35,7 +35,8 @@ class Runtime:
     #             weights are PTQ'd *offline* (core/ptq.py) so carry no
     #             in-graph quantization ops (the paper's deployment)
     # fake_full — also quantize weights in-graph (calibration/ablation runs)
-    # packed    — weights stored as packed 4-bit buffers, decoded in-graph
+    # packed    — weights stored as packed 4-bit buffers; BCQ activations
+    #             only (the fused linear encodes no other act_format)
     quant_mode: str = "none"
     bcq_cfg: BCQConfig = BCQConfig()
     compute_dtype: Any = jnp.bfloat16
@@ -70,12 +71,10 @@ class Runtime:
     # (kernels/paged_attention.py) instead of the gather+dequant jnp path.
     # interpret-mode on CPU (tests); native on TPU.
     paged_kernel: bool = False
-    # route quant_mode='packed' linears through the fused single-launch
-    # quantize→decode→GEMM (kernels/bcq_linear.py) instead of the in-graph
-    # decode_packed_weight + einsum: raw activations encode in VMEM, the
-    # weights decode by lane gathers, packed activations never
-    # round-trip HBM.  Native Pallas on TPU; elsewhere the ref-oracle
-    # composition runs (bit-exact with the two-launch kernels).
+    # quant_mode='packed' linears: True runs the fused single-launch
+    # quantize→decode→GEMM Pallas kernel (kernels/bcq_linear.py) on TPU
+    # and its jnp oracle elsewhere; False runs the oracle everywhere
+    # (ref.fused_linear_ref), as paged_kernel=False does for attention.
     fused_linear: bool = True
     mesh: Any = None  # required (hashable) when flash_decode is set
     # opt-in online quantization-error probe (serving telemetry): a
@@ -86,6 +85,13 @@ class Runtime:
     # different sinks are distinct jit-static values (separate caches,
     # no silent cross-engine probe sharing).
     quant_probe: Any = None
+
+    def __post_init__(self):
+        if self.quant_mode == "packed" and self.act_format != "bcq":
+            raise ValueError(
+                f"quant_mode='packed' encodes BCQ activations only; "
+                f"act_format={self.act_format!r} needs quant_mode 'fake' or 'fake_full'"
+            )
 
 
 # ------------------------------------------------------------------- init
@@ -171,35 +177,18 @@ def _emit_quant_probe(x, rt: "Runtime", cb, tag) -> None:
     )
 
 
-def decode_packed_weight(pk: dict, cfg: BCQConfig, cb: jax.Array) -> jax.Array:
-    """In-graph dequant of a packed (..., N, K) weight: storage stays 4-bit
-    in HBM; decode is gather + multiply (the jnp analogue of the Pallas
-    decode-GEMM's VMEM stage)."""
-    idx = bcq.unpack_nibbles(pk["idx"]).astype(jnp.int32)  # (..., N, K)
-    k = idx.shape[-1]
-    nb = k // cfg.block_len
-    sel = bcq.unpack_nibbles(pk["sel"]).astype(jnp.int32)[..., :nb]
-    ratio = formats.bits_to_e4m3(pk["scale"])  # (..., N, K/L_A)
-    flat = cb.reshape(-1)
-    sel_s = jnp.repeat(sel, cfg.block_len, axis=-1)
-    vals = flat[sel_s * cfg.n_entries + idx]
-    s_x = pk["s_x"]
-    if getattr(s_x, "ndim", 0):  # per-expert scales (E,) on stacked weights
-        s_x = s_x.reshape(s_x.shape + (1,) * (ratio.ndim - s_x.ndim))
-    inv = jnp.repeat(1.0 / (ratio * s_x), cfg.array_len, axis=-1)
-    return vals * inv  # f32 (..., N, K)
-
-
 def fused_packed_linear(x, pk: dict, rt: "Runtime", cb, s_x=None):
-    """quant_mode='packed' linear through the fused single-launch Pallas
-    kernel (kernels/bcq_linear.py via ops.w4a4_linear_fused): activations
-    encode on the fly in VMEM; the packed weight buffers stream 4.5-bit.
-    x: (..., K); pk: pack_weight dict (N, K).  Returns f32-accurate (..., N)
-    in x.dtype."""
+    """quant_mode='packed' linear: one fused W4A4 launch
+    (kernels/bcq_linear.py via ops.w4a4_linear_fused) where
+    ``rt.fused_linear`` is set, its jnp oracle otherwise.  Activations
+    encode on the fly (in VMEM in the kernel); the packed weight buffers
+    stream 4.5-bit.  x: (..., K); pk: pack_weight dict (N, K).  Returns
+    (..., N) in x.dtype."""
     from repro.kernels import ops as kernel_ops
 
+    impl = {} if rt.fused_linear else {"impl": "ref"}
     return kernel_ops.w4a4_linear_fused(
-        x, kernel_ops.packed_operand(pk), cb, rt.bcq_cfg, s_x=s_x
+        x, kernel_ops.packed_operand(pk), cb, rt.bcq_cfg, s_x=s_x, **impl
     )
 
 
@@ -231,23 +220,16 @@ def qdense_shared(x, ps: list, rt: Runtime, cb, tag=None):
     the activation ONCE and reuse — bit-identical to per-head quantization
     (same xq), but 1× instead of N× encode cost/traffic.
 
-    The fused packed path skips the shared pre-quantization: each fused
-    kernel encodes the raw tile in VMEM (per-head encode is bit-identical
-    anyway — same x, same dynamic s_X — and never round-trips HBM).  The
-    fused kernel implements the paper's BCQ activation quantizer only, so
-    other act_formats ('none' = W4A16, mx4/…) keep the pre-quantized
-    decode+einsum path.
+    The packed path skips the shared pre-quantization: each fused linear
+    encodes the raw activation itself (per-head encode is bit-identical
+    anyway — same x, same dynamic s_X — and in the kernel never
+    round-trips HBM).
 
     ``tag`` names this head group for the opt-in quant-error probe —
     emitted ONCE here (the heads share one activation encode), with the
     per-head qdense calls untagged so the probe never double-counts."""
     _emit_quant_probe(x, rt, cb, tag)
-    if (
-        rt.quant_mode == "packed" and rt.fused_linear
-        and rt.act_format == "bcq" and cb is not None
-    ):
-        return [qdense(x, p, rt, cb) for p in ps]
-    if rt.quant_mode in ("fake", "fake_full", "packed") and cb is not None:
+    if rt.quant_mode in ("fake", "fake_full") and cb is not None:
         xq = _quantize_act(x.astype(jnp.float32), rt, cb)
         rt = dataclasses.replace(rt, act_format="_pre_quantized")
         x = xq
@@ -261,16 +243,12 @@ def qdense(x, p, rt: Runtime, cb: Optional[jax.Array], tag=None):
     if rt.act_format != "_pre_quantized":
         _emit_quant_probe(x, rt, cb, tag)
     dt = rt.compute_dtype
-    if rt.act_format == "_pre_quantized" and rt.quant_mode != "none" and cb is not None:
-        # input already quantized by qdense_shared
-        if rt.quant_mode in ("fake", "fake_full"):
-            wk = p["kernel"].astype(dt)
-            if rt.quant_mode == "fake_full":
-                wk = _fq(p["kernel"].astype(jnp.float32).T, cb, rt.bcq_cfg).astype(dt).T
-            y = jnp.einsum("...k,kn->...n", x.astype(dt), wk)
-        else:
-            w = decode_packed_weight(p["kernel_packed"], rt.bcq_cfg, cb).astype(dt)
-            y = jnp.einsum("...k,nk->...n", x.astype(dt), w)
+    if rt.act_format == "_pre_quantized":
+        # input already quantized by qdense_shared ('fake' / 'fake_full')
+        wk = p["kernel"].astype(dt)
+        if rt.quant_mode == "fake_full":
+            wk = _fq(p["kernel"].astype(jnp.float32).T, cb, rt.bcq_cfg).astype(dt).T
+        y = jnp.einsum("...k,kn->...n", x.astype(dt), wk)
         if "bias" in p:
             y = y + p["bias"].astype(y.dtype)
         return y
@@ -286,12 +264,7 @@ def qdense(x, p, rt: Runtime, cb: Optional[jax.Array], tag=None):
         wq = _fq(wt, cb, rt.bcq_cfg)
         y = jnp.einsum("...k,nk->...n", xq.astype(dt), wq.astype(dt))
     elif rt.quant_mode == "packed":
-        if rt.fused_linear:
-            y = fused_packed_linear(x, p["kernel_packed"], rt, cb).astype(dt)
-        else:
-            xq = _fq(x.astype(jnp.float32), cb, rt.bcq_cfg).astype(dt)
-            w = decode_packed_weight(p["kernel_packed"], rt.bcq_cfg, cb).astype(dt)
-            y = jnp.einsum("...k,nk->...n", xq, w)
+        y = fused_packed_linear(x, p["kernel_packed"], rt, cb).astype(dt)
     else:
         raise ValueError(rt.quant_mode)
     if "bias" in p:

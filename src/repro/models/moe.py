@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro.core import bcq
 from repro.models import layers
-from repro.models.layers import Runtime, init_dense, qdense
+from repro.models.layers import Runtime, init_dense
 
 
 def init_moe(key, cfg, rt: Runtime):
@@ -57,23 +57,17 @@ def _expert_matmul(xe, wp, rt: Runtime, cb, tag=None):
         wq = bcq.fake_quant(wt, cb, rt.bcq_cfg).astype(dt)
         return jnp.einsum("eck,enk->ecn", xq, wq)
     if rt.quant_mode == "packed":
-        if rt.fused_linear:
-            # one fused quantize→decode→GEMM launch per expert; s_X stays
-            # the per-tensor reduction over ALL experts' tokens so the
-            # activation quantization is bit-identical to the unfused
-            # fake_quant(xe) path
-            s_x = bcq.tensor_scale(xe.astype(jnp.float32), rt.bcq_cfg)
-            pks = wp["kernel_packed"]
-            outs = [
-                layers.fused_packed_linear(
-                    xe[e], jax.tree.map(lambda v: v[e], pks), rt, cb, s_x=s_x
-                )
-                for e in range(xe.shape[0])
-            ]
-            return jnp.stack(outs).astype(dt)
-        xq = bcq.fake_quant(xe.astype(jnp.float32), cb, rt.bcq_cfg).astype(dt)
-        w = layers.decode_packed_weight(wp["kernel_packed"], rt.bcq_cfg, cb).astype(dt)
-        return jnp.einsum("eck,enk->ecn", xq, w)
+        # one fused W4A4 linear per expert; s_X stays the per-tensor
+        # reduction over ALL experts' tokens, as fake_quant(xe) takes it
+        s_x = bcq.tensor_scale(xe.astype(jnp.float32), rt.bcq_cfg)
+        pks = wp["kernel_packed"]
+        outs = [
+            layers.fused_packed_linear(
+                xe[e], jax.tree.map(lambda v: v[e], pks), rt, cb, s_x=s_x
+            )
+            for e in range(xe.shape[0])
+        ]
+        return jnp.stack(outs).astype(dt)
     raise ValueError(rt.quant_mode)
 
 

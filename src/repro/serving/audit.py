@@ -36,7 +36,8 @@ Report mode collects every violation into an :class:`AuditReport`;
 fail-fast mode (``engine.audit(strict=True)`` or ``Engine(strict=True)``)
 raises :class:`AuditError` on the first dirty report.  The walk is pure
 host-side numpy/dict reads — no device work — so ``audit_every=N`` can
-ride production ticks (benchmarks/paged_bench.py gates the overhead).
+ride production ticks (tests/test_telemetry.py checks that audits add
+no device sync and no retrace).
 """
 from __future__ import annotations
 

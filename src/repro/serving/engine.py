@@ -115,8 +115,8 @@ on the profiler's host timeline while a trace runs.  Telemetry splits
 attribution at depth 2: ``decode_tick_s`` holds the
 dispatch-only launch span, ``decode_sync_s`` the blocking fetch, and
 ``decode_host_gap_s`` the between-launch host gap on quiet ticks —
-the pipeline's figure of merit (BENCH_paged.json gates on
-``device_bound``: mean gap < mean full device tick).  Sampled
+the pipeline's figure of merit (the benchmark's ``host_gap_ms`` reads
+it on the chip, ``perfbench/metrics/host_gap_ms.py``).  Sampled
 requests merge their token on device too (``_SET_TOK`` overlay after
 the launch) — the per-tick padded-logits host fetch is gone.
 Depth-2 ≡ depth-1 ≡ profile_sync bit-identity across cache kinds ×
@@ -221,12 +221,9 @@ _ROW_STATS = jax.jit(
         jnp.all(jnp.isfinite(lg[:, -1, :]), axis=-1),
     )
 )
-# Jitted greedy row fetch for the nan_guard=False legacy path.  The raw
-# ``next_greedy_tokens`` call used to run EAGERLY here — one un-jitted
-# argmax dispatch per tick that cost ~38% of steady-state throughput
-# (BENCH_paged.json guard_overhead_pct: -38.9 before the fix).  Routing
-# it through jit makes the guards-on/off bench gate measure guard cost,
-# not fetch implementation.
+# Jitted greedy row fetch for the nan_guard=False path: one launch per
+# tick, as on the guards-on path (run eagerly, ``next_greedy_tokens`` is
+# a separate un-jitted dispatch every tick).
 _GREEDY_ROW = jax.jit(next_greedy_tokens)
 # Device-side merge of one sampled token into the launch's token vector
 # (the index rides as a traced scalar, so every slot shares one trace).

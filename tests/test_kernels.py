@@ -1,10 +1,14 @@
-"""Pallas kernel validation: interpret-mode kernels vs pure-jnp oracles.
+"""Pallas kernel validation: the fused W4A4 linear's pieces and the
+kernel itself (interpret mode) vs the pure-jnp oracles of kernels/ref.py.
 
 Contract: the *decoded values* (and therefore every downstream GEMM) must be
-bit-identical between the Pallas kernels and kernels/ref.py.  Raw selector /
-index bytes may legitimately differ when a block ties between two codebooks
-(or a codebook holds duplicate INT6 entries) — tests check value equality.
+bit-identical between the kernel's in-VMEM encode and kernels/ref.py.  Raw
+selector / index bytes may legitimately differ when a block ties between two
+codebooks (or a codebook holds duplicate INT6 entries) — tests check value
+equality.
 """
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,8 +17,7 @@ import pytest
 from repro.core import bcq
 from repro.core.bcq import BCQConfig
 from repro.kernels import ops, ref
-from repro.kernels.bcq_matmul import bcq_matmul_pallas
-from repro.kernels.bcq_quantize import bcq_quantize_pallas
+from repro.kernels.common import encode_tile
 
 CFGS = [
     BCQConfig(),  # paper default g64 / L_b 8 / N_c 8
@@ -43,21 +46,39 @@ def _dists(key, shape, dtype, kind):
     return x.astype(dtype)
 
 
+@partial(jax.jit, static_argnames=("cfg", "tile_m", "tile_k"))
+def _encode_tiles(x, cb, s_x, cfg, tile_m, tile_k):
+    """``common.encode_tile`` over an (M, K) operand in (tile_k, tile_m)
+    K-major tiles, as the fused kernel walks its activation slab;
+    returns row-major idx (M, K), sel (M, K/L_b), ratio (M, K/L_A) and
+    the chosen codewords (M, K)."""
+    m, k = x.shape
+    tiles = x.reshape(m // tile_m, tile_m, k // tile_k, tile_k).transpose(0, 2, 3, 1)
+    outs = jax.vmap(jax.vmap(lambda t: encode_tile(t, cb, s_x, cfg)))(tiles)
+    return [a.transpose(0, 3, 1, 2).reshape(m, -1) for a in outs]
+
+
 @pytest.mark.parametrize("cfg", CFGS, ids=lambda c: c.tag())
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("kind", ["normal", "heavy", "outlier"])
 def test_quantize_kernel_matches_ref(cfg, dtype, kind):
+    """The fused linear's in-VMEM encode (``common.encode_tile``, on a
+    64 × 256 tile grid) against ``ref.quantize_ref``: equal scales, and
+    decoded values equal up to near-ties between codebooks."""
     cb = _codebooks(cfg)
-    x = _dists(jax.random.PRNGKey(7), (128, 512), dtype, kind)
-    s_x = bcq.tensor_scale(x.astype(jnp.float32), cfg)
-    ip, sp, rt = bcq_quantize_pallas(
-        x.astype(jnp.float32), cb, s_x, cfg, tile_m=64, tile_k=256, interpret=True
-    )
-    ip2, sp2, rt2 = ref.quantize_ref(x.astype(jnp.float32), cb, cfg, s_x)
+    x = _dists(jax.random.PRNGKey(7), (128, 512), dtype, kind).astype(jnp.float32)
+    s_x = bcq.tensor_scale(x, cfg)
+    idx, sel, rt, q = _encode_tiles(x, cb, s_x, cfg, tile_m=64, tile_k=256)
+    ip, sp = bcq.pack_nibbles(idx), bcq.pack_nibbles(sel)
+    ip2, sp2, rt2 = ref.quantize_ref(x, cb, cfg, s_x)
     np.testing.assert_array_equal(np.asarray(rt), np.asarray(rt2))
     inv = 1.0 / (rt * s_x)
     d1 = ref.decode_ref(ip, sp, inv, cb, cfg)
     d2 = ref.decode_ref(ip2, sp2, inv, cb, cfg)
+    # the encode's chosen codewords are the decode of its own codes
+    np.testing.assert_array_equal(
+        np.asarray(q * jnp.repeat(inv, cfg.array_len, axis=-1)), np.asarray(d1)
+    )
     # Decoded values must agree except where a block ties between two
     # codebooks at *identical* MSE — so compare per-block quantization error.
     xf = np.asarray(x, np.float32)
@@ -73,39 +94,46 @@ def test_quantize_kernel_matches_ref(cfg, dtype, kind):
     "mnk", [(128, 128, 512), (64, 192, 1024), (256, 128, 512)]
 )
 def test_matmul_kernel_matches_ref(cfg, mnk):
+    """The fused kernel against its oracle ``ref.fused_linear_ref``."""
     m, n, k = mnk
     cb = _codebooks(cfg)
     a = _dists(jax.random.PRNGKey(1), (m, k), jnp.float32, "normal")
     w = _dists(jax.random.PRNGKey(2), (n, k), jnp.float32, "heavy")
-    pa = ops.quantize(a, cb, cfg, impl="ref")
-    pw = ops.quantize(w, cb, cfg, impl="ref")
-    o_ref = ops.matmul(pa, pw, cb, cfg, impl="ref")
-    o_pl = ops.matmul(pa, pw, cb, cfg, impl="pallas", tile_m=64, tile_n=64, tile_k=256)
+    pw = ops.quantize(w, cb, cfg)
+    o_ref = ops.w4a4_linear_fused(a, pw, cb, cfg, impl="ref")
+    o_pl = ops.w4a4_linear_fused(
+        a, pw, cb, cfg, impl="pallas", tile_m=64, tile_n=64, tile_k=256
+    )
     np.testing.assert_allclose(np.asarray(o_pl), np.asarray(o_ref), rtol=1e-5, atol=1e-4)
 
 
 @pytest.mark.parametrize("cfg", CFGS[:2], ids=lambda c: c.tag())
 def test_matmul_matches_fake_quant_path(cfg):
-    """Packed W4A4 GEMM == fake-quant (quantize-dequantize bf16) GEMM."""
+    """Fused W4A4 kernel == the fake_full emulation (both operands
+    quantize-dequantized, then one f32 GEMM)."""
     cb = _codebooks(cfg)
     a = _dists(jax.random.PRNGKey(3), (96, 512), jnp.float32, "outlier")
     w = _dists(jax.random.PRNGKey(4), (160, 512), jnp.float32, "normal")
-    pa = ops.quantize(a, cb, cfg, impl="pallas")
-    pw = ops.quantize(w, cb, cfg, impl="pallas")
-    out = ops.matmul(pa, pw, cb, cfg, impl="pallas", tile_m=32, tile_n=32, tile_k=256)
+    pw = ops.quantize(w, cb, cfg)
+    out = ops.w4a4_linear_fused(
+        a, pw, cb, cfg, impl="pallas", tile_m=32, tile_n=32, tile_k=256
+    )
     expect = bcq.fake_quant(a, cb, cfg) @ bcq.fake_quant(w, cb, cfg).T
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=1e-4, atol=1e-3)
 
 
 def test_quantize_wrapper_pads_ragged_shapes():
-    # rows and K not tile-aligned (K must still be a multiple of L_A)
+    """Rows, columns and K none tile-aligned (K still a multiple of L_A):
+    the fused linear's wrapper pads K to its tile with whole arrays whose
+    weight dequant scales are zero, so the padding adds nothing."""
     cfg = BCQConfig()
     cb = _codebooks(cfg)
     x = _dists(jax.random.PRNGKey(5), (100, 320), jnp.float32, "normal")
     w = _dists(jax.random.PRNGKey(6), (70, 320), jnp.float32, "normal")
-    pa = ops.quantize(x, cb, cfg, impl="pallas", tile_m=64, tile_k=256)
-    pw = ops.quantize(w, cb, cfg, impl="pallas", tile_m=64, tile_k=256)
-    out = ops.matmul(pa, pw, cb, cfg, impl="pallas", tile_m=64, tile_n=64, tile_k=256)
+    pw = ops.quantize(w, cb, cfg)
+    out = ops.w4a4_linear_fused(
+        x, pw, cb, cfg, impl="pallas", tile_m=64, tile_n=64, tile_k=256
+    )
     expect = bcq.fake_quant(x, cb, cfg) @ bcq.fake_quant(w, cb, cfg).T
     assert out.shape == (100, 70)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=1e-4, atol=1e-3)
@@ -116,8 +144,8 @@ def test_w4a4_linear_nd_input():
     cb = _codebooks(cfg)
     x = _dists(jax.random.PRNGKey(8), (2, 16, 256), jnp.bfloat16, "normal")
     w = _dists(jax.random.PRNGKey(9), (128, 256), jnp.float32, "normal")
-    pw = ops.quantize(w, cb, cfg, impl="ref")
-    out = ops.w4a4_linear(x, pw, cb, cfg, impl="ref")
+    pw = ops.quantize(w, cb, cfg)
+    out = ops.w4a4_linear_fused(x, pw, cb, cfg, impl="pallas", tile_m=32, tile_n=64)
     assert out.shape == (2, 16, 128) and out.dtype == jnp.bfloat16
     expect = bcq.fake_quant(x.astype(jnp.float32).reshape(-1, 256), cb, cfg) @ bcq.fake_quant(w, cb, cfg).T
     np.testing.assert_allclose(
@@ -136,7 +164,7 @@ def test_decode_rows_matches_ref_decode(cfg):
 
     cb = _codebooks(cfg)
     w = _dists(jax.random.PRNGKey(10), (24, 5 * cfg.array_len), jnp.float32, "heavy")
-    p = ops.quantize(w, cb, cfg, impl="ref")
+    p = ops.quantize(w, cb, cfg)
     got = decode_rows(
         p.idx_packed.astype(jnp.int32), p.sel_packed.astype(jnp.int32),
         p.inv_scale, flat_codebook(cb), cfg, w.shape[1],
@@ -173,6 +201,6 @@ def test_packed_storage_bit_accounting():
     cfg = BCQConfig(n_codebooks=16)  # 4 + 4/8 + 8/64 = 4.625 bits
     cb = _codebooks(cfg)
     x = jax.random.normal(jax.random.PRNGKey(0), (256, 1024))
-    p = ops.quantize(x, cb, cfg, impl="ref")
+    p = ops.quantize(x, cb, cfg)
     bits = (p.idx_packed.size + p.sel_packed.size) * 8 + p.inv_scale.size * 8
     assert bits / x.size == pytest.approx(cfg.bitwidth(), abs=1e-9)

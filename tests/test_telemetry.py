@@ -264,6 +264,48 @@ def test_default_level_adds_no_traces_or_syncs(api_params):
     assert eng.stats["decode_ticks"] > 0
 
 
+@pytest.mark.parametrize("depth,profile", [(2, False), (1, True)], ids=["depth2", "profile_sync"])
+def test_launch_histograms_count_engine_launches(api_params, depth, profile):
+    """One ``prefill_launch_s`` observation per batched prefill launch and
+    one ``decode_tick_s`` observation per decode tick, whether the chunked
+    engine runs pipelined (depth 2: launch spans) or synchronous
+    (``profile_sync``: full device spans)."""
+    api, params = api_params
+    eng = PagedEngine(api, params, n_slots=2, max_len=MAX_LEN, page_size=PS,
+                      chunked_prefill=True, prefill_chunk=PS,
+                      pipeline_depth=depth, profile_sync=profile)
+    _run(eng, _prompts(), 4)
+    assert eng.stats["prefill_launches"] > 0 and eng.stats["decode_ticks"] > 0
+    assert eng.telemetry.h_prefill.count == eng.stats["prefill_launches"]
+    assert eng.telemetry.h_decode.count == eng.stats["decode_ticks"]
+
+
+def test_guards_add_no_traces_or_syncs(api_params):
+    """The robustness guards (NaN guard + an invariant audit every 4 ticks)
+    against both off, on the same warm workload: identical tokens,
+    identical device-sync counts, zero retraces, and every periodic audit
+    clean."""
+    api, params = api_params
+
+    def guarded(on):
+        return PagedEngine(api, params, n_slots=2, max_len=MAX_LEN, page_size=PS,
+                           nan_guard=on, audit_every=4 if on else 0)
+
+    for on in (True, False):  # warm each variant's shape buckets
+        _run(guarded(on), _prompts(), 4)
+    outs, syncs = {}, {}
+    for on in (True, False):
+        eng = guarded(on)
+        outs[on] = {rid: r.out for rid, r in _run(eng, _prompts(), 4).items()}
+        assert sum(eng.trace_counts().values()) == 0, on
+        syncs[on] = eng.telemetry.registry.counter("device_syncs").value
+        if on:
+            assert eng._last_audit is not None and eng._last_audit.ok
+            assert eng.health()["counters"]["audit_failures"] == 0
+    assert outs[True] == outs[False]
+    assert syncs[True] == syncs[False] > 0
+
+
 def test_preemption_timeline_single_submit_two_admits(api_params):
     """A preempted-and-resumed request keeps ONE timeline: one submit,
     an admit per (re)admission, TTFT measured from the original submit."""
